@@ -58,7 +58,6 @@ exits non-zero before it.
 from __future__ import annotations
 
 import argparse
-import collections
 import json
 import os
 import sys
@@ -78,10 +77,6 @@ ROUNDING_UNITS = 32
 BOUND = ((1.0 - ALPHA) * TOL + ROUNDING_UNITS * 2.0 ** -24) / ALPHA
 CONSERVATION_BOUND = 1e-4  # relative; f32 mass drift over the sweeps
 INITIAL_SCORE = 1000.0
-# jax.monitoring events of this process; a persistent-cache hit is also
-# counted as a compile (JAX's compile-duration event wraps the lookup)
-JAX_EVENTS: collections.Counter = collections.Counter()
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 SERVE_SPANS = ("service.poll", "service.wal_append", "service.ingest",
                "ingest.pipeline", "service.graph_apply", "service.refresh",
                "routed.plan_build", "converge.edges", "service.snapshot")
@@ -381,7 +376,7 @@ def phase_serve(args, on_chip: bool) -> dict:
                      if name in spans},
           "xla_compiles": compiles["compiles"],
           "xla_compile_s": compiles["compile_seconds"],
-          "xla_cache_hits": JAX_EVENTS[CACHE_HIT_EVENT], **host_memory()})
+          "xla_cache_hits": compiles["cache_hits"], **host_memory()})
 
     got = {(addresses[s], addresses[d]): float(v)
            for s, d, v in zip(src, dst, val)}
@@ -582,10 +577,6 @@ def main(argv=None) -> int:
     emit({"phase": "device", "compile_cache": enable_compile_cache()})
     trace.enable()
     trace.install_compile_tracking()
-    import jax
-
-    jax.monitoring.register_event_listener(
-        lambda event, **_: JAX_EVENTS.update([event]))
     try:
         if args.chips:
             phase_sharded(args, on_chip)

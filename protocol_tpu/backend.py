@@ -141,18 +141,22 @@ class JaxSparseBackend(ConvergeBackend):
             converge_sparse_adaptive_semiring,
             converge_sparse_fixed,
             converge_sparse_fixed_semiring,
+            fetch_converged,
             operator_arrays,
             resolve_semiring,
             timed_converge,
         )
+        from .utils import trace
 
         sr = resolve_semiring(semiring)
         op = build_operator(n, src, dst, val, valid)
-        arrs = operator_arrays(op, dtype=self.dtype, alpha=alpha)
-        if s0 is None:
-            s0 = jnp.asarray(op.valid, dtype=self.dtype) * float(initial_score)
-        else:
-            s0 = jnp.asarray(np.asarray(s0), dtype=self.dtype)
+        with trace.span("converge.stage", backend="jax-sparse"):
+            arrs = operator_arrays(op, dtype=self.dtype, alpha=alpha)
+            if s0 is None:
+                s0 = jnp.asarray(op.valid, dtype=self.dtype) * float(
+                    initial_score)
+            else:
+                s0 = jnp.asarray(np.asarray(s0), dtype=self.dtype)
         # the jit-cache identity of the converge call: bucket geometry +
         # dtype + static loop bound. A compile for a signature already
         # compiled once is a shape leak (steady-state recompile).
@@ -162,29 +166,25 @@ class JaxSparseBackend(ConvergeBackend):
         if sr.name != "plusmul":
             sig = sig + (sr.name,)
             if tol is None:
-                scores = timed_converge(
-                    "jax-sparse", n, len(src), sig,
-                    lambda: converge_sparse_fixed_semiring(
-                        arrs, s0, sr, num_iterations),
-                    fixed_iterations=num_iterations, semiring=sr.name)
-                return np.asarray(scores)
-            scores, iters, delta = timed_converge(
-                "jax-sparse", n, len(src), sig,
-                lambda: converge_sparse_adaptive_semiring(
-                    arrs, s0, sr, tol=tol, max_iterations=num_iterations),
-                semiring=sr.name)
-            return np.asarray(scores), int(iters), float(delta)
-        if tol is None:
-            scores = timed_converge(
-                "jax-sparse", n, len(src), sig,
-                lambda: converge_sparse_fixed(arrs, s0, num_iterations),
-                fixed_iterations=num_iterations)
-            return np.asarray(scores)
-        scores, iters, delta = timed_converge(
-            "jax-sparse", n, len(src), sig,
-            lambda: converge_sparse_adaptive(
-                arrs, s0, tol=tol, max_iterations=num_iterations))
-        return np.asarray(scores), int(iters), float(delta)
+                def call():
+                    return converge_sparse_fixed_semiring(
+                        arrs, s0, sr, num_iterations)
+            else:
+                def call():
+                    return converge_sparse_adaptive_semiring(
+                        arrs, s0, sr, tol=tol, max_iterations=num_iterations)
+        elif tol is None:
+            def call():
+                return converge_sparse_fixed(arrs, s0, num_iterations)
+        else:
+            def call():
+                return converge_sparse_adaptive(
+                    arrs, s0, tol=tol, max_iterations=num_iterations)
+        out = timed_converge(
+            "jax-sparse", n, len(src), sig, call,
+            fixed_iterations=num_iterations if tol is None else None,
+            semiring=sr.name)
+        return fetch_converged(out, fixed=tol is None)
 
     def converge_topics(
         self, n, src, dst, val, valid, s0_topics, max_iterations,
@@ -205,6 +205,7 @@ class JaxSparseBackend(ConvergeBackend):
             resolve_semiring,
             timed_converge,
         )
+        from .utils import trace
 
         sr = resolve_semiring(semiring)
         s0k = np.asarray(s0_topics, dtype=np.float64)
@@ -212,8 +213,9 @@ class JaxSparseBackend(ConvergeBackend):
             raise ValueError(
                 f"s0_topics must be [K, {n}] (got {s0k.shape})")
         op = build_operator(n, src, dst, val, valid)
-        arrs = operator_arrays(op, dtype=self.dtype, alpha=alpha)
-        s0k = jnp.asarray(s0k, dtype=self.dtype)
+        with trace.span("converge.stage", backend="jax-sparse"):
+            arrs = operator_arrays(op, dtype=self.dtype, alpha=alpha)
+            s0k = jnp.asarray(s0k, dtype=self.dtype)
         sig = ("sparse-topics", n, int(s0k.shape[0]),
                tuple(b.shape for b in op.bucket_idx), str(s0k.dtype),
                int(max_iterations), sr.name)
@@ -222,8 +224,9 @@ class JaxSparseBackend(ConvergeBackend):
             lambda: converge_sparse_topics(
                 arrs, s0k, sr, tol=tol, max_iterations=max_iterations),
             semiring=sr.name)
-        return (np.asarray(scores), np.asarray(iters),
-                np.asarray(delta))
+        with trace.span("converge.fetch"):
+            return (np.asarray(scores), np.asarray(iters),
+                    np.asarray(delta))
 
 
 class JaxRoutedBackend(JaxSparseBackend):
@@ -239,7 +242,11 @@ class JaxRoutedBackend(JaxSparseBackend):
     ):
         import jax.numpy as jnp
 
-        from .ops.converge import resolve_semiring, timed_converge
+        from .ops.converge import (
+            fetch_converged,
+            resolve_semiring,
+            timed_converge,
+        )
         from .ops.routed import (
             build_routed_operator,
             converge_routed_adaptive,
@@ -248,19 +255,21 @@ class JaxRoutedBackend(JaxSparseBackend):
             converge_routed_fixed_semiring,
             routed_arrays,
         )
+        from .utils import trace
 
         sr = resolve_semiring(semiring)
         op = operator
         if op is None:
             op = build_routed_operator(n, src, dst, val, valid)
-        arrs, static = routed_arrays(op, dtype=self.dtype, alpha=alpha)
-        if s0 is None:
-            s0 = jnp.asarray(op.initial_scores(initial_score,
-                                               dtype=self.dtype))
-        else:
-            # node-order warm start → state-slot order
-            s0 = jnp.asarray(op.scores_from_nodes(np.asarray(s0),
-                                                  dtype=self.dtype))
+        with trace.span("converge.stage", backend="jax-routed"):
+            arrs, static = routed_arrays(op, dtype=self.dtype, alpha=alpha)
+            if s0 is None:
+                s0 = jnp.asarray(op.initial_scores(initial_score,
+                                                   dtype=self.dtype))
+            else:
+                # node-order warm start → state-slot order
+                s0 = jnp.asarray(op.scores_from_nodes(np.asarray(s0),
+                                                      dtype=self.dtype))
         # the static tuple IS the routed jit cache key (hashable by
         # construction) — plus dtype and the static loop bound
         sig = ("routed", static, str(s0.dtype),
@@ -270,33 +279,28 @@ class JaxRoutedBackend(JaxSparseBackend):
             # semiring twins for broadcast/reduce only
             sig = sig + (sr.name,)
             if tol is None:
-                scores = timed_converge(
-                    "jax-routed", n, int(op.nnz), sig,
-                    lambda: converge_routed_fixed_semiring(
-                        arrs, static, s0, sr, num_iterations),
-                    fixed_iterations=num_iterations, semiring=sr.name)
-                return op.scores_for_nodes(np.asarray(scores))
-            scores, iters, delta = timed_converge(
-                "jax-routed", n, int(op.nnz), sig,
-                lambda: converge_routed_adaptive_semiring(
-                    arrs, static, s0, sr, tol=tol,
-                    max_iterations=num_iterations),
-                semiring=sr.name)
-            return (op.scores_for_nodes(np.asarray(scores)), int(iters),
-                    float(delta))
-        if tol is None:
-            scores = timed_converge(
-                "jax-routed", n, int(op.nnz), sig,
-                lambda: converge_routed_fixed(arrs, static, s0,
-                                              num_iterations),
-                fixed_iterations=num_iterations)
-            return op.scores_for_nodes(np.asarray(scores))
-        scores, iters, delta = timed_converge(
-            "jax-routed", n, int(op.nnz), sig,
-            lambda: converge_routed_adaptive(
-                arrs, static, s0, tol=tol, max_iterations=num_iterations))
-        return (op.scores_for_nodes(np.asarray(scores)), int(iters),
-                float(delta))
+                def call():
+                    return converge_routed_fixed_semiring(
+                        arrs, static, s0, sr, num_iterations)
+            else:
+                def call():
+                    return converge_routed_adaptive_semiring(
+                        arrs, static, s0, sr, tol=tol,
+                        max_iterations=num_iterations)
+        elif tol is None:
+            def call():
+                return converge_routed_fixed(arrs, static, s0,
+                                             num_iterations)
+        else:
+            def call():
+                return converge_routed_adaptive(
+                    arrs, static, s0, tol=tol, max_iterations=num_iterations)
+        out = timed_converge(
+            "jax-routed", n, int(op.nnz), sig, call,
+            fixed_iterations=num_iterations if tol is None else None,
+            semiring=sr.name)
+        return fetch_converged(out, fixed=tol is None,
+                               to_nodes=op.scores_for_nodes)
 
     def converge_topics(
         self, n, src, dst, val, valid, s0_topics, max_iterations,
@@ -314,6 +318,7 @@ class JaxRoutedBackend(JaxSparseBackend):
             converge_routed_topics,
             routed_arrays,
         )
+        from .utils import trace
 
         sr = resolve_semiring(semiring)
         s0k = np.asarray(s0_topics, dtype=np.float64)
@@ -323,10 +328,11 @@ class JaxRoutedBackend(JaxSparseBackend):
         op = operator
         if op is None:
             op = build_routed_operator(n, src, dst, val, valid)
-        arrs, static = routed_arrays(op, dtype=self.dtype, alpha=alpha)
-        s0k = jnp.asarray(
-            np.stack([op.scores_from_nodes(row, dtype=self.dtype)
-                      for row in s0k]))
+        with trace.span("converge.stage", backend="jax-routed"):
+            arrs, static = routed_arrays(op, dtype=self.dtype, alpha=alpha)
+            s0k = jnp.asarray(
+                np.stack([op.scores_from_nodes(row, dtype=self.dtype)
+                          for row in s0k]))
         sig = ("routed-topics", static, int(s0k.shape[0]),
                str(s0k.dtype), int(max_iterations), sr.name)
         scores, iters, delta = timed_converge(
@@ -335,6 +341,7 @@ class JaxRoutedBackend(JaxSparseBackend):
                 arrs, static, s0k, sr, tol=tol,
                 max_iterations=max_iterations),
             semiring=sr.name)
-        return (np.stack([op.scores_for_nodes(row)
-                          for row in np.asarray(scores)]),
-                np.asarray(iters), np.asarray(delta))
+        with trace.span("converge.fetch"):
+            return (np.stack([op.scores_for_nodes(row)
+                              for row in np.asarray(scores)]),
+                    np.asarray(iters), np.asarray(delta))

@@ -10,8 +10,8 @@ capture window on a LIVE serve daemon via its proof job queue — with
   spans attribute device work accurately instead of dispatch-skewed;
 - an optional **xprof capture** (``--xprof DIR`` →
   ``trace.device_trace``) whose start/stop events share the workload's
-  trace id with the JSONL span stream (``--jsonl PATH``) — the offline
-  xprof timeline joins the span stream by trace id + wall clock;
+  trace id with the JSONL span stream (``--jsonl PATH``); the spans
+  themselves are host events of the xprof timeline;
 - **XLA compile tracking** installed, so the report separates compile
   from execute;
 

@@ -340,6 +340,7 @@ def _lane_perm_pallas(x2d, idx2d):
         kern,
         out_shape=jax.ShapeDtypeStruct((T, L), x2d.dtype),
         grid=(T // tile,),
+        name="clos_lane_perm",
         in_specs=[
             pl.BlockSpec((tile, L), lambda i: (i, 0)),
             pl.BlockSpec((tile, L), lambda i: (i, 0)),
@@ -358,12 +359,18 @@ def _use_pallas() -> bool:
 
 
 def _lane_perm(x, stage, pallas: bool):
-    x2 = x.reshape(-1, 128)
-    i2 = stage.reshape(-1, 128)
-    # Mosaic tiles are 8 sublanes deep; tiny stages fall back to XLA
-    if pallas and x2.shape[0] >= 8:
-        return _lane_perm_pallas(x2, i2)
-    return jnp.take_along_axis(x2, i2.astype(jnp.int32), axis=1)
+    """One lane-gather stage on ``[rows, 128]`` tiles of ``x``. Device
+    operations carry two scope names: ``clos.lane_perm`` for the gather
+    itself and ``clos.relayout`` for the reshapes into its tiles (XLA
+    fuses them with the shuffles between stages into one copy)."""
+    with jax.named_scope("clos.relayout"):
+        x2 = x.reshape(-1, 128)
+        i2 = stage.reshape(-1, 128)
+    with jax.named_scope("clos.lane_perm"):
+        # Mosaic tiles are 8 sublanes deep; tiny stages fall back to XLA
+        if pallas and x2.shape[0] >= 8:
+            return _lane_perm_pallas(x2, i2)
+        return jnp.take_along_axis(x2, i2.astype(jnp.int32), axis=1)
 
 
 def route_core(x, stages, si: int, e_sub: int, bits: tuple, pallas: bool):
@@ -371,21 +378,31 @@ def route_core(x, stages, si: int, e_sub: int, bits: tuple, pallas: bool):
     subproblems batched contiguously (every reshape/transpose below works
     on El-sized chunks, so subproblem boundaries are never crossed). The
     sharded executor (parallel/routed.py) uses B > 1 for the device-local
-    middle levels of a distributed route."""
+    middle levels of a distributed route.
+
+    The shuffles between stages run under the ``clos.relayout`` scope,
+    as do :func:`_lane_perm`'s reshapes; only the gathers are
+    ``clos.lane_perm``."""
     E = x.size
     for li in range(len(bits) - 1):
         El = 1 << (e_sub - 7 * li)
         B, m = E // El, El >> 7
         x = _lane_perm(x, stages[si], pallas)
-        x = x.reshape(B, m, 128).swapaxes(1, 2).reshape(E)
+        with jax.named_scope("clos.relayout"):
+            x = x.reshape(B, m, 128).swapaxes(1, 2).reshape(E)
         si += 1
-    x = _lane_perm(x, stages[si], pallas).reshape(E)
+    x = _lane_perm(x, stages[si], pallas)
+    with jax.named_scope("clos.relayout"):
+        x = x.reshape(E)
     si += 1
     for li in reversed(range(len(bits) - 1)):
         El = 1 << (e_sub - 7 * li)
         B, m = E // El, El >> 7
-        x = x.reshape(B, 128, m).swapaxes(1, 2).reshape(E)
-        x = _lane_perm(x, stages[si], pallas).reshape(E)
+        with jax.named_scope("clos.relayout"):
+            x = x.reshape(B, 128, m).swapaxes(1, 2).reshape(E)
+        x = _lane_perm(x, stages[si], pallas)
+        with jax.named_scope("clos.relayout"):
+            x = x.reshape(E)
         si += 1
     return x
 

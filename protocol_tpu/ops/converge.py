@@ -207,6 +207,20 @@ def timed_converge(backend: str, n: int, edges: int, signature, call,
     return out
 
 
+def fetch_converged(out, fixed: bool, to_nodes=np.asarray):
+    """The host end of a ``converge_edges`` call, under the
+    ``converge.fetch`` span: the device-to-host copy of the scores,
+    mapped to node order by ``to_nodes``, and in adaptive mode the
+    iteration count and final delta. ``out`` is what
+    :func:`timed_converge` returned; the span pairs with the callers'
+    ``converge.stage`` span around the operator's staging."""
+    with trace.span("converge.fetch"):
+        if fixed:
+            return to_nodes(np.asarray(out))
+        scores, iters, delta = out
+        return to_nodes(np.asarray(scores)), int(iters), float(delta)
+
+
 def warm_start_scores(prev, n: int, valid, initial_score: float):
     """Project a previous score vector onto a (possibly grown) peer set —
     the warm-start seam of the incremental refresh loop
@@ -422,7 +436,10 @@ def adaptive_loop(step, s0: jnp.ndarray, tol: float, max_iterations: int,
     def body(state):
         s_prev, s, i, _ = state
         s_next = step(s)
-        delta = jnp.sum(jnp.abs(s_next - s)) / norm
+        # the convergence check is the sweep's last phase: named with
+        # the routed sweep's tail (ops.routed.spmv_routed)
+        with jax.named_scope("tail"):
+            delta = jnp.sum(jnp.abs(s_next - s)) / norm
         if accel_every:
             d1 = s - s_prev
             d2 = s_next - s

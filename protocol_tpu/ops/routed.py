@@ -50,6 +50,7 @@ from .converge import (
     semiring_tail,
 )
 from ..graph import filter_edges, stable_argsort_bounded
+from ..utils import trace
 
 __all__ = [
     "RoutedOperator",
@@ -534,14 +535,12 @@ def build_routed_operator(
     ``ptpu_routed_plan_build_seconds`` so operator-cache misses are
     attributable in the serve daemon's refresh latency.
     """
-    from ..utils import trace as _trace
-
     # every full routing-plan compilation anywhere in the process —
     # the write-path cost the delta engine exists to amortize away; the
     # serve smoke asserts this stays FLAT under weight-revision churn
-    _trace.counter("operator_full_builds").inc()
-    with _trace.timed("routed_plan_build_seconds", "routed.plan_build",
-                      n=n, edges=len(src)):
+    trace.counter("operator_full_builds").inc()
+    with trace.timed("routed_plan_build_seconds", "routed.plan_build",
+                     n=n, edges=len(src)):
         op = _build_routed_operator(n, src, dst, val, valid, min_width,
                                     prefer_native)
     return op
@@ -565,7 +564,11 @@ def ensure_edge_slots(op: RoutedOperator, src, dst, weight) \
 def _build_routed_operator(
     n, src, dst, val, valid, min_width: int, prefer_native: bool,
 ) -> RoutedOperator:
-    src, dst, weight, valid_mask, dangling = filter_edges(n, src, dst, val, valid)
+    # child spans of routed.plan_build, one per phase: filter,
+    # bucketize, assemble, route, finish
+    with trace.span("routed.plan.filter"):
+        src, dst, weight, valid_mask, dangling = filter_edges(
+            n, src, dst, val, valid)
 
     # the two sides bucketize independently — overlap them on threads
     # (numpy's big sorts release the GIL), like the two plan builds
@@ -573,17 +576,65 @@ def _build_routed_operator(
     import os as _os
     from concurrent.futures import ThreadPoolExecutor
 
-    if _os.environ.get("PTPU_PLAN_SERIAL", "0") != "1":
-        with ThreadPoolExecutor(max_workers=2) as pool:
-            out_f = pool.submit(_bucketize_blocked, n, src, dst, weight,
-                                min_width)
-            in_f = pool.submit(_bucketize_blocked, n, dst, src, weight,
-                               min_width)
-            out_side, in_side = out_f.result(), in_f.result()
-    else:
-        out_side = _bucketize_blocked(n, src, dst, weight, min_width)
-        in_side = _bucketize_blocked(n, dst, src, weight, min_width)
+    with trace.span("routed.plan.bucketize"):
+        if _os.environ.get("PTPU_PLAN_SERIAL", "0") != "1":
+            with ThreadPoolExecutor(max_workers=2) as pool:
+                out_f = pool.submit(_bucketize_blocked, n, src, dst, weight,
+                                    min_width)
+                in_f = pool.submit(_bucketize_blocked, n, dst, src, weight,
+                                   min_width)
+                out_side, in_side = out_f.result(), in_f.result()
+        else:
+            out_side = _bucketize_blocked(n, src, dst, weight, min_width)
+            in_side = _bucketize_blocked(n, dst, src, weight, min_width)
+    with trace.span("routed.plan.assemble"):
+        perm, sperm, state_e, state_to_node, live = _assemble_permutations(
+            n, out_side, in_side)
+    # both plans at once: the state plan (2^state_e, typically 16x
+    # smaller) rides in the edge plan's shadow — the threaded plan
+    # build is the DEFAULT full-rebuild fast path
+    with trace.span("routed.plan.route"):
+        plan, splan = plan_routes((perm, sperm), prefer_native=prefer_native)
 
+    with trace.span("routed.plan.finish"):
+        N2 = 1 << state_e
+        live_slots = np.nonzero(live)[0]
+        live_nodes = state_to_node[live]
+        valid_state = np.zeros(N2, dtype=np.float32)
+        valid_state[live_slots] = valid_mask[live_nodes].astype(np.float32)
+        dangling_state = np.zeros(N2, dtype=np.float32)
+        dangling_state[live_slots] = dangling[live_nodes].astype(np.float32)
+
+        return RoutedOperator(
+            n=n,
+            n_valid=int(valid_mask.sum()),
+            nnz=len(src),
+            out_widths=out_side.widths,
+            out_xs=out_side.xs,
+            out_weight=out_side.weight,
+            n_src_pos=out_side.n_pos,
+            state_to_node=state_to_node,
+            in_widths=in_side.widths,
+            in_xs=in_side.xs,
+            in_n_pos=in_side.n_pos,
+            edge_e=plan.e,
+            edge_bits=plan.bits,
+            edge_stages=plan.stages,
+            state_e=splan.e,
+            state_bits=splan.bits,
+            state_stages=splan.stages,
+            valid=valid_state,
+            dangling=dangling_state,
+            out_edge_slot=out_side.edge_slot,
+            min_width=min_width,
+        )
+
+
+def _assemble_permutations(n, out_side: _Side, in_side: _Side):
+    """The state order and the two permutations the plan routes:
+    ``perm`` (edge route, in slot <- out slot) and ``sperm`` (state
+    route, state slot <- z position). Returns ``(perm, sperm, state_e,
+    state_to_node, live)``."""
     # state order: source-row positions first (column-major grids, dead
     # pad slots included), then out-edge-less nodes
     n_src_pos = out_side.n_pos
@@ -600,9 +651,7 @@ def _build_routed_operator(
     state_to_node = np.full(N2, -1, dtype=np.int64)
     state_to_node[src_pos] = src_nodes
     state_to_node[n_src_pos : n_src_pos + len(rest)] = rest
-    node_to_state = np.full(n, -1, dtype=np.int64)
     live = state_to_node >= 0
-    node_to_state[state_to_node[live]] = np.nonzero(live)[0]
 
     # --- edge route: in slot <- out slot ---------------------------------
     # int32 throughout: these are 2^28-sized working arrays at 10M-peer
@@ -640,39 +689,7 @@ def _build_routed_operator(
     free_zero = np.nonzero(~sp_used)[0]   # z pads + tail: all zeros
     need = np.nonzero(sperm < 0)[0]
     sperm[need] = free_zero[: len(need)]
-    # both plans at once: the state plan (2^state_e, typically 16x
-    # smaller) rides in the edge plan's shadow — the threaded plan
-    # build is the DEFAULT full-rebuild fast path
-    plan, splan = plan_routes((perm, sperm), prefer_native=prefer_native)
-
-    valid_state = np.zeros(N2, dtype=np.float32)
-    valid_state[live_slots] = valid_mask[live_nodes].astype(np.float32)
-    dangling_state = np.zeros(N2, dtype=np.float32)
-    dangling_state[live_slots] = dangling[live_nodes].astype(np.float32)
-
-    return RoutedOperator(
-        n=n,
-        n_valid=int(valid_mask.sum()),
-        nnz=len(src),
-        out_widths=out_side.widths,
-        out_xs=out_side.xs,
-        out_weight=out_side.weight,
-        n_src_pos=n_src_pos,
-        state_to_node=state_to_node,
-        in_widths=in_side.widths,
-        in_xs=in_side.xs,
-        in_n_pos=in_side.n_pos,
-        edge_e=plan.e,
-        edge_bits=plan.bits,
-        edge_stages=plan.stages,
-        state_e=splan.e,
-        state_bits=splan.bits,
-        state_stages=splan.stages,
-        valid=valid_state,
-        dangling=dangling_state,
-        out_edge_slot=out_side.edge_slot,
-        min_width=min_width,
-    )
+    return perm, sperm, state_e, state_to_node, live
 
 
 class RoutedStatic(NamedTuple):
@@ -764,22 +781,33 @@ def spmv_routed(arrs: dict, static: RoutedStatic, s: jnp.ndarray) -> jnp.ndarray
       folded in with one scatter-add; unused capacity carries weight 0.
       The routing program itself never changes, so edge churn costs
       O(batch), not O(graph).
+
+    Each phase runs under a ``jax.named_scope`` (``broadcast``,
+    ``edge_route``, ``reduce``, ``state_route``, ``tail``): the names
+    land in the device operations' metadata, so a profiler trace sums
+    device time per phase whatever XLA numbers its instructions.
     """
-    s_b = s * arrs["inv_row_scale"] if "inv_row_scale" in arrs else s
-    x = blocked_broadcast(arrs, s_b, static.out_widths, static.out_xs,
-                          1 << static.edge_e)
-    y = _apply_route_jit(x, arrs["edge_stages"], static.edge_e,
-                         static.edge_bits, static.pallas)
-    z = blocked_reduce(arrs, y, static.in_widths, static.in_xs,
-                       static.in_n_pos, 1 << static.state_e)
-    base = _apply_route_jit(z, arrs["state_stages"], static.state_e,
-                            static.state_bits, static.pallas)
-    if "tail_w" in arrs:
-        # tail weights are TRUE normalized weights (val / row_sum_now,
-        # maintained host-side per batch) — no inv_row_scale here
-        base = base + jnp.zeros_like(base).at[arrs["tail_dst"]].add(
-            arrs["tail_w"] * s[arrs["tail_src"]])
-    return dangling_and_damping(arrs, s, base)
+    with jax.named_scope("broadcast"):
+        s_b = s * arrs["inv_row_scale"] if "inv_row_scale" in arrs else s
+        x = blocked_broadcast(arrs, s_b, static.out_widths, static.out_xs,
+                              1 << static.edge_e)
+    with jax.named_scope("edge_route"):
+        y = _apply_route_jit(x, arrs["edge_stages"], static.edge_e,
+                             static.edge_bits, static.pallas)
+    with jax.named_scope("reduce"):
+        z = blocked_reduce(arrs, y, static.in_widths, static.in_xs,
+                           static.in_n_pos, 1 << static.state_e)
+    with jax.named_scope("state_route"):
+        base = _apply_route_jit(z, arrs["state_stages"], static.state_e,
+                                static.state_bits, static.pallas)
+    with jax.named_scope("tail"):
+        if "tail_w" in arrs:
+            # tail weights are TRUE normalized weights (val /
+            # row_sum_now, maintained host-side per batch) — no
+            # inv_row_scale here
+            base = base + jnp.zeros_like(base).at[arrs["tail_dst"]].add(
+                arrs["tail_w"] * s[arrs["tail_src"]])
+        return dangling_and_damping(arrs, s, base)
 
 
 @partial(jax.jit, static_argnames=("static", "num_iterations"))
@@ -880,20 +908,26 @@ def spmv_routed_semiring(arrs: dict, static: RoutedStatic, s,
     semiring tail. ``sr`` is static under jit, so the (+,×) branch
     compiles to exactly :func:`spmv_routed` and every other algebra
     reuses the operator's route plans untouched (routes are
-    permutations — no algebra appears in them). The delta engine's
+    permutations — no algebra appears in them); the phases carry
+    :func:`spmv_routed`'s named scopes. The delta engine's
     patched-matvec keys (``inv_row_scale``/``tail_*``) are a (+,×)
     normalization concept and never reach this path."""
     if sr.name == "plusmul":
         return spmv_routed(arrs, static, s)
-    x = blocked_broadcast_semiring(arrs, s, static.out_widths,
-                                   static.out_xs, 1 << static.edge_e, sr)
-    y = _apply_route_jit(x, arrs["edge_stages"], static.edge_e,
-                         static.edge_bits, static.pallas)
-    z = blocked_reduce_semiring(arrs, y, static.in_widths, static.in_xs,
-                                static.in_n_pos, 1 << static.state_e, sr)
-    base = _apply_route_jit(z, arrs["state_stages"], static.state_e,
-                            static.state_bits, static.pallas)
-    return semiring_tail(sr, arrs, s, base)
+    with jax.named_scope("broadcast"):
+        x = blocked_broadcast_semiring(arrs, s, static.out_widths,
+                                       static.out_xs, 1 << static.edge_e, sr)
+    with jax.named_scope("edge_route"):
+        y = _apply_route_jit(x, arrs["edge_stages"], static.edge_e,
+                             static.edge_bits, static.pallas)
+    with jax.named_scope("reduce"):
+        z = blocked_reduce_semiring(arrs, y, static.in_widths, static.in_xs,
+                                    static.in_n_pos, 1 << static.state_e, sr)
+    with jax.named_scope("state_route"):
+        base = _apply_route_jit(z, arrs["state_stages"], static.state_e,
+                                static.state_bits, static.pallas)
+    with jax.named_scope("tail"):
+        return semiring_tail(sr, arrs, s, base)
 
 
 @partial(jax.jit, static_argnames=("static", "sr", "num_iterations"))

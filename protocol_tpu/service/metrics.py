@@ -133,7 +133,8 @@ HISTOGRAM_FAMILIES = {
 # front for the same reason (the serve-smoke asserts a steady-state
 # recompile count of 0 and a shed count of 0 under the watermark — the
 # series must exist to be assertable)
-DECLARED_COUNTERS = ("xla_compiles", "xla_steady_recompiles",
+DECLARED_COUNTERS = ("xla_compiles", "xla_cache_hits",
+                     "xla_steady_recompiles",
                      "operator_full_builds", "refresh_sweep_scope",
                      "proof_pool_shed", "proof_pool_affinity",
                      "proof_pool_stolen", "prove_shards",

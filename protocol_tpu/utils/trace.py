@@ -7,7 +7,10 @@ net-new for this framework. This module provides:
 
 - ``span(name, **fields)``: nested wall-clock spans (context manager)
   carrying ``span_id``/``parent_id`` and, when a trace context is
-  active, the ``trace_id``(s) of the work items flowing through them;
+  active, the ``trace_id``(s) of the work items flowing through them.
+  Each enabled span also opens a ``jax.profiler.TraceAnnotation`` of
+  exactly its name (where jax is imported), so under a running
+  profiler every span is a host event on the device trace's clock;
 - ``context(trace_id=...)`` / ``context(trace_ids=[...])``: thread-local
   trace-context propagation — a cheap id (attestation digest, job id,
   HTTP request id) stamped on every span/event emitted inside, so one
@@ -23,8 +26,8 @@ net-new for this framework. This module provides:
 - a process-global ``Tracer`` with JSONL export and a summary table,
 - ``device_trace(log_dir)``: optional passthrough to the JAX profiler
   (xprof) for device-side timelines — it emits start/stop events into
-  the JSONL stream carrying the log dir and the active trace ids, so an
-  xprof capture is joinable against the span stream offline;
+  the JSONL stream carrying the log dir and the active trace ids; the
+  spans themselves land in the capture as host events;
 - **sync-span mode** (``sync_spans()`` / ``PTPU_TRACE_SYNC=1``): device
   dispatch is asynchronous, so a span around a dispatch-only call
   attributes the compute cost to whichever later span happens to block.
@@ -32,8 +35,10 @@ net-new for this framework. This module provides:
   boundaries, making per-stage attribution accurate (at the cost of the
   production overlap — a profiling mode, not a serving default);
 - **XLA compile tracking** (:class:`CompileTracker`): a
-  ``jax.monitoring`` event listener recording every backend compile as
-  ``ptpu_xla_compiles_total{site}`` + ``ptpu_xla_compile_seconds``,
+  ``jax.monitoring`` event listener recording every fresh backend
+  compile as ``ptpu_xla_compiles_total{site}`` +
+  ``ptpu_xla_compile_seconds`` and every load from JAX's persistent
+  compilation cache as ``ptpu_xla_cache_hits_total{site}``,
   with ``compile_watch(site, signature)`` marking a code region — a
   compile inside a region whose signature was already compiled once is
   a *steady-state recompile* (a shape leak in a cache that should have
@@ -59,6 +64,7 @@ import itertools
 import json
 import math
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -255,9 +261,20 @@ class CompileTracker:
     Thread model: ``jax.monitoring`` invokes listeners on the thread
     that runs the compile (the dispatching thread), so the per-thread
     compile count a :meth:`watch` reads cannot be inflated by a
-    concurrent thread's compiles."""
+    concurrent thread's compiles.
+
+    Cache loads: ``EVENT`` times JAX's ``compile_or_get_cached``, so it
+    also fires when the executable comes from the persistent
+    compilation cache. That path records ``CACHE_HIT_EVENT`` first, on
+    the same thread, inside the timed call; the next ``EVENT`` of the
+    thread is then a cache load, counted in ``xla_cache_hits`` and kept
+    out of ``xla_compiles`` / ``xla_compile_seconds``. The per-thread
+    count and seconds (:meth:`watch`, :meth:`thread_compile_seconds`)
+    take both: either way the in-memory jit cache missed and the thread
+    was not running the program."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
     SEEN_CAP = 4096  # signature memory bound (long-lived daemons)
 
     def __init__(self, tracer: "Tracer"):
@@ -267,13 +284,14 @@ class CompileTracker:
         self.installed = False
         self.compiles = 0
         self.compile_seconds = 0.0
+        self.cache_hits = 0
         self.steady_recompiles = 0
         self.recompile_warning = False
         self.last_site: str | None = None
         self._seen: set = set()
 
     def install(self) -> bool:
-        """Register the listener (idempotent); False when jax is
+        """Register the listeners (idempotent); False when jax is
         unavailable — compile tracking degrades to a no-op, never an
         import error on jax-less hosts."""
         if self.installed:
@@ -282,22 +300,37 @@ class CompileTracker:
             import jax.monitoring
         except Exception:  # pragma: no cover - jax-less host
             return False
+        jax.monitoring.register_event_listener(self._on_cache_hit)
         jax.monitoring.register_event_duration_secs_listener(
             self._on_event)
         self.installed = True
         return True
 
+    def _on_cache_hit(self, event: str, **kw) -> None:
+        if event == self.CACHE_HIT_EVENT:
+            self._local.hit = True
+
     def _on_event(self, event: str, duration: float, **kw) -> None:
-        if event != self.EVENT or not self._tracer.enabled:
+        if event != self.EVENT:
+            return
+        hit = getattr(self._local, "hit", False)
+        self._local.hit = False
+        if not self._tracer.enabled:
             return
         site = getattr(self._local, "site", None) or "other"
         with self._lock:
-            self.compiles += 1
-            self.compile_seconds += float(duration)
+            if hit:
+                self.cache_hits += 1
+            else:
+                self.compiles += 1
+                self.compile_seconds += float(duration)
             self.last_site = site
         self._local.count = getattr(self._local, "count", 0) + 1
         self._local.seconds = (getattr(self._local, "seconds", 0.0)
                                + float(duration))
+        if hit:
+            self._tracer.counter("xla_cache_hits").inc(site=site)
+            return
         self._tracer.counter("xla_compiles").inc(site=site)
         self._tracer.histogram("xla_compile_seconds").observe(
             float(duration), site=site)
@@ -358,6 +391,7 @@ class CompileTracker:
         with self._lock:
             self.compiles = 0
             self.compile_seconds = 0.0
+            self.cache_hits = 0
             self.steady_recompiles = 0
             self.recompile_warning = False
             self.last_site = None
@@ -370,18 +404,32 @@ class CompileTracker:
                 "installed": self.installed,
                 "compiles": self.compiles,
                 "compile_seconds": round(self.compile_seconds, 6),
+                "cache_hits": self.cache_hits,
                 "steady_recompiles": self.steady_recompiles,
                 "recompile_warning": self.recompile_warning,
                 "last_site": self.last_site,
             }
 
 
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named exactly ``name`` (no
+    kwargs: they would become part of the event name), or a no-op
+    context where jax is not imported — no profiler can run there, and
+    a span never imports jax itself."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name)
+
+
 @dataclass
 class SpanRecord:
     name: str
-    start: float           # EPOCH seconds (time.time at span open) —
-    duration: float        # alignable with event timestamps; duration
-    depth: int             # is measured on the monotonic clock
+    start: float           # EPOCH seconds (time.time at span open): the
+    duration: float        # JSONL ``ts``. The span's profiler event is
+    depth: int             # stamped on the profiler's clock (that of the
+                           # device events); duration is measured on the
+                           # monotonic clock
     fields: dict
     span_id: str = ""
     parent_id: str | None = None
@@ -613,7 +661,8 @@ class Tracer:
         wall = time.time()
         t0 = time.perf_counter()
         try:
-            yield
+            with _profiler_annotation(name):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self._local.depth = depth
@@ -1019,8 +1068,9 @@ def device_trace(log_dir: str):
     production paths never die on profiler availability.
 
     Start/stop events land in the JSONL stream carrying ``log_dir`` and
-    the active trace context, so an offline xprof timeline is joinable
-    against the span stream by trace id + wall-clock window."""
+    the active trace context. Spans need no join: every enabled span
+    inside the capture is itself a host event of the xprof timeline,
+    on the profiler's clock beside the device operations."""
     try:
         import jax
 

@@ -744,9 +744,11 @@ def run_worker(fabric, name: str, poll: float = 0.05,
                         while not ev.wait(max(0.2, ttl / 3.0)):
                             fabric.heartbeat(uid, name, ttl=ttl)
 
-                    beat = threading.Thread(target=_beat, daemon=True,
-                                            name=f"fabric-beat-{name}")
-                    beat.start()
+                    # not ``beat``: that name is the stall-watchdog
+                    # callback the loop calls on every pass
+                    beater = threading.Thread(target=_beat, daemon=True,
+                                              name=f"fabric-beat-{name}")
+                    beater.start()
                     try:
                         # the unit's span joins the submitting job's
                         # trace (job_id IS the proof job / trace id),
@@ -778,7 +780,7 @@ def run_worker(fabric, name: str, poll: float = 0.05,
                                     error=str(e))
                     finally:
                         done.set()
-                        beat.join(timeout=2.0)
+                        beater.join(timeout=2.0)
                     if max_units is not None and executed >= max_units:
                         break
                 if not progressed:

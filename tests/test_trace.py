@@ -368,12 +368,184 @@ def test_compile_watch_cache_hit_does_not_latch(tracer):
 
 def test_compile_stats_shape(tracer):
     stats = trace.compile_stats()
-    for key in ("installed", "compiles", "compile_seconds",
+    for key in ("installed", "compiles", "compile_seconds", "cache_hits",
                 "steady_recompiles", "recompile_warning", "last_site"):
         assert key in stats
 
 
+_CACHE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+from protocol_tpu.utils import trace
+jax.config.update("jax_enable_compilation_cache", True)
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+trace.enable()
+trace.install_compile_tracking()
+with trace.compile_watch("probe"):
+    jax.jit(lambda x: jnp.sin(x) * 3 + 1)(jnp.arange(8.0)).block_until_ready()
+stats = trace.compile_stats()
+print(json.dumps({"compiles": stats["compiles"],
+                  "cache_hits": stats["cache_hits"],
+                  "hits_counter": trace.counter_total("xla_cache_hits",
+                                                      site="probe"),
+                  "compiles_counter": trace.counter_total("xla_compiles",
+                                                          site="probe")}))
+"""
+
+
+def test_compile_tracker_counts_cache_loads_apart(tmp_path):
+    """A persistent-cache load fires JAX's compile-duration event too:
+    the first process compiles (and writes the cache), a fresh one with
+    the same cache directory only loads — a hit, not a compile."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = tmp_path / "probe.py"
+    script.write_text(_CACHE_PROBE)
+    cache = tmp_path / "xla"
+
+    def run():
+        out = subprocess.run(
+            [sys.executable, str(script), str(cache)], cwd=repo,
+            env=dict(os.environ, PYTHONPATH=repo), capture_output=True,
+            text=True, timeout=120, check=True)
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    first = run()
+    assert first["compiles"] >= 1 and first["cache_hits"] == 0
+    assert first["compiles_counter"] == first["compiles"]
+    assert first["hits_counter"] == 0
+    second = run()
+    assert second["compiles"] == 0
+    assert second["cache_hits"] == first["compiles"]
+    assert second["hits_counter"] == second["cache_hits"]
+    assert second["compiles_counter"] == 0
+
+
+# --- spans on the profiler's clock ------------------------------------------
+
+
+def _host_events(log_dir) -> dict:
+    """{event name: [(start ns, end ns)]} of the host plane of the one
+    trace under ``log_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def _traced_spans(log_dir):
+    import jax
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        # span fields stay out of the profiler event's name
+        with trace.span("t.outer", size=4):
+            with trace.span("t.inner"):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(log_dir)
+
+
+def test_spans_land_on_the_profiler_host_plane(tracer, tmp_path):
+    pytest.importorskip("jax")
+    events = _traced_spans(tmp_path / "on")
+    ((o0, o1),) = events["t.outer"]
+    ((i0, i1),) = events["t.inner"]
+    assert o0 <= i0 <= i1 <= o1
+    trace.TRACER.disable()
+    try:
+        events = _traced_spans(tmp_path / "off")
+    finally:
+        trace.TRACER.enable()
+    assert "t.outer" not in events and "t.inner" not in events
+
+
 # --- converge instrumentation ------------------------------------------------
+
+
+ROUTED_SCOPES = ("broadcast", "edge_route", "reduce", "state_route", "tail",
+                 "clos.lane_perm", "clos.relayout")
+
+
+def test_routed_sweep_scopes_reach_the_optimized_hlo():
+    """The named scopes of the routed sweep survive into the compiled
+    program's op metadata, where a profiler trace reads them."""
+    import re
+
+    jnp = pytest.importorskip("jax.numpy")
+    from protocol_tpu.graph import barabasi_albert_edges
+    from protocol_tpu.ops.routed import (
+        build_routed_operator,
+        converge_routed_adaptive,
+        routed_arrays,
+    )
+
+    n = 600
+    src, dst, val = barabasi_albert_edges(n, 3, seed=1)
+    op = build_routed_operator(n, src, dst, val)
+    arrs, static = routed_arrays(op, alpha=0.1)
+    s0 = jnp.asarray(op.initial_scores(1000.0))
+    text = converge_routed_adaptive.lower(
+        arrs, static, s0, tol=1e-6, max_iterations=50).compile().as_text()
+    paths = [p.split("/") for p in set(re.findall(r'op_name="([^"]*)"', text))]
+    for scope in ROUTED_SCOPES:
+        held = [p for p in paths if scope in p]
+        assert held, scope
+        assert all(p[0] == "jit(converge_routed_adaptive)" for p in held)
+
+
+@pytest.mark.parametrize("backend,entry", [
+    ("JaxSparseBackend", "converge_edges"),
+    ("JaxRoutedBackend", "converge_edges"),
+    ("JaxSparseBackend", "converge_topics"),
+    ("JaxRoutedBackend", "converge_topics"),
+])
+def test_converge_spans_stage_edges_fetch(tracer, backend, entry):
+    """Every converge entry point runs its host phases under the same
+    three spans; the routed build adds its plan phases."""
+    np = pytest.importorskip("numpy")
+    from protocol_tpu import backend as backends
+    from protocol_tpu.graph import barabasi_albert_edges
+
+    n = 200
+    src, dst, val = barabasi_albert_edges(n, 3, seed=5)
+    valid = np.ones(n, dtype=bool)
+    be = getattr(backends, backend)()
+    if entry == "converge_edges":
+        be.converge_edges(n, src, dst, val, valid, 1000.0, 100, tol=1e-6,
+                          alpha=0.1)
+    else:
+        be.converge_topics(n, src, dst, val, valid,
+                           np.full((2, n), 1000.0), 100, alpha=0.1)
+    spans = trace.summary()
+    for name in ("converge.stage", "converge.edges", "converge.fetch"):
+        assert spans[name]["count"] == 1, name
+    plan = ("routed.plan_build", "routed.plan.filter",
+            "routed.plan.bucketize", "routed.plan.assemble",
+            "routed.plan.route", "routed.plan.finish")
+    if backend == "JaxRoutedBackend":
+        children = sum(spans[p]["total_s"] for p in plan[1:])
+        assert all(spans[p]["count"] == 1 for p in plan)
+        assert children <= spans["routed.plan_build"]["total_s"]
+    else:
+        assert not set(plan) & set(spans)
 
 
 def test_record_converge_stats_instruments(tracer):
@@ -381,9 +553,9 @@ def test_record_converge_stats_instruments(tracer):
 
     record_converge_stats("test-backend", 10, 1e-7, 2.0, n=100)
     assert trace.TRACER.gauge("converge_iterations").value(
-        backend="test-backend") == 10
+        backend="test-backend", semiring="plusmul") == 10
     assert trace.TRACER.gauge("converge_residual").value(
-        backend="test-backend") == pytest.approx(1e-7)
+        backend="test-backend", semiring="plusmul") == pytest.approx(1e-7)
     series = trace.TRACER.histogram("converge_sweep_seconds").series()
     assert series and series[0][1]["count"] == 1
     assert series[0][1]["sum"] == pytest.approx(0.2)  # 2.0s / 10 iters
@@ -391,7 +563,7 @@ def test_record_converge_stats_instruments(tracer):
     # residual untouched
     record_converge_stats("fixed-backend", 5, None, 1.0)
     assert trace.TRACER.gauge("converge_iterations").value(
-        backend="fixed-backend") == 5
+        backend="fixed-backend", semiring="plusmul") == 5
 
 
 def test_converge_edges_records_gauges_and_watch(tracer):
@@ -406,9 +578,9 @@ def test_converge_edges_records_gauges_and_watch(tracer):
         n, src, dst, val, np.ones(n, dtype=bool), 1000.0, 200, tol=1e-6)
     assert iters > 0
     assert trace.TRACER.gauge("converge_iterations").value(
-        backend="jax-sparse") == iters
+        backend="jax-sparse", semiring="plusmul") == iters
     assert trace.TRACER.gauge("converge_residual").value(
-        backend="jax-sparse") == pytest.approx(delta)
+        backend="jax-sparse", semiring="plusmul") == pytest.approx(delta)
     sweeps = trace.TRACER.histogram("converge_sweep_seconds").series()
     assert any(dict(items).get("backend") == "jax-sparse"
                for items, _ in sweeps)
