@@ -10,7 +10,9 @@ vector unit executes at streaming bandwidth:
 - **lane permutation**: ``out[row, j] = x[row, idx[row, j]]`` over
   ``[rows, 128]`` tiles — Mosaic's ``tpu.dynamic_gather`` along lanes,
   ~60 G elements/s on v5e (vs ~0.14 G for XLA's general gather);
-- **transpose/reshape** between stages — XLA copies at HBM bandwidth.
+- **transpose/reshape** between stages — XLA copies at HBM bandwidth;
+  an innermost level narrower than 128 slots needs none, and runs as
+  one kernel over its row blocks (``_inner_level``).
 
 A permutation of ``E = 128·m`` slots factors (König edge-coloring of the
 128-regular bipartite row multigraph) into: an input lane permutation, a
@@ -36,7 +38,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
+
+from ..utils import trace
 
 __all__ = [
     "RoutePlan",
@@ -45,6 +50,7 @@ __all__ = [
     "plan_routes",
     "apply_route",
     "apply_route_np",
+    "executor_stages",
     "route_bits",
 ]
 
@@ -326,6 +332,42 @@ def apply_route_np(plan: RoutePlan, x: np.ndarray) -> np.ndarray:
     return x
 
 
+def _fused_level(bits: tuple) -> bool:
+    """Whether the innermost level of a route over ``bits`` runs as one
+    row-block stage (:func:`_inner_level`): it has a level above its
+    base, and its sub-problems of ``m = 2^bits[-1] < 128`` slots would
+    otherwise be shuffled through an ``[..., 128, m]`` array, whose
+    minor dimension of ``m`` the TPU pads to 128 lanes."""
+    return len(bits) > 1 and bits[-1] < 7
+
+
+@partial(jax.jit, static_argnames=("m",))
+def _base_rows(stage, m: int):
+    """Row-index form of a base stage of ``m``-lane blocks under a
+    level of ``m``-row sub-problems. Between that level's shuffles the
+    base stage moves slot ``(b, i, j)`` of the ``[B, m, 128]`` view (row
+    i, lane j) to row ``stage[b·128m + j·m + i] & (m − 1)`` of the same
+    block and lane: lane j's entries are the transposed ``(j, i)`` ones,
+    less their block base."""
+    return (stage.reshape(-1, 128, m).swapaxes(1, 2) & (m - 1)).reshape(-1)
+
+
+def executor_stages(stages, bits: tuple, route: str, si: int = 0) -> tuple:
+    """The stage tuple :func:`route_core` runs for a plan's ``stages``
+    over ``bits`` (starting at stage ``si``): the plan's own arrays,
+    except that a fused innermost level (:func:`_fused_level`) takes its
+    base stage in row-index form (:func:`_base_rows`), derived on the
+    device once per staged route. Each such route counts once in the
+    ``clos_inner_fused`` counter under ``route``."""
+    stages = tuple(stages)
+    if not _fused_level(bits):
+        return stages
+    trace.counter("clos_inner_fused").inc(route=route)
+    k = si + len(bits) - 1
+    return (stages[:k] + (_base_rows(stages[k], 1 << bits[-1]),)
+            + stages[k + 1:])
+
+
 def _lane_perm_pallas(x2d, idx2d):
     """One routing stage: per-row lane gather via tpu.dynamic_gather."""
     T, L = x2d.shape
@@ -373,33 +415,127 @@ def _lane_perm(x, stage, pallas: bool):
         return jnp.take_along_axis(x2, i2.astype(jnp.int32), axis=1)
 
 
+def _row_perm(a, rows, m: int):
+    """Per-lane row permutation inside each group of ``m`` consecutive
+    rows of a ``[C, 128]`` value: ``out[r, j] = a[r − r % m + rows[r, j],
+    j]``. A sublane gather reaches the 8 rows of one vreg only, so a
+    group taller than that selects each output vreg among the gathers
+    of its ``m/8`` source vregs."""
+    sub = lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    out = []
+    for v in range(a.shape[0] // 8):
+        r = rows[8 * v: 8 * v + 8]
+        if m <= 8:
+            out.append(jnp.take_along_axis(
+                a[8 * v: 8 * v + 8], (sub & ~(m - 1)) + r, axis=0))
+            continue
+        g0 = v - v % (m // 8)
+        lo, hi = r & 7, r >> 3
+        acc = jnp.take_along_axis(a[8 * g0: 8 * g0 + 8], lo, axis=0)
+        for u in range(1, m // 8):
+            src = a[8 * (g0 + u): 8 * (g0 + u) + 8]
+            acc = jnp.where(hi == u, jnp.take_along_axis(src, lo, axis=0),
+                            acc)
+        out.append(acc)
+    return jnp.concatenate(out, axis=0)
+
+
+def _inner_level_pallas(x2d, in2d, rows2d, out2d, m: int,
+                        interpret: bool = False):
+    """The innermost level of a route in one pass over ``[rows, 128]``
+    tiles: lane gather ``in2d``, the row permutation ``rows2d`` inside
+    each ``m``-row block (:func:`_row_perm`), lane gather ``out2d``. The
+    tile is walked in chunks of 256 rows, whole blocks and whole packed
+    uint8 vregs (32 rows): on a v5e 32-row chunks took twice as long."""
+    T, L = x2d.shape
+    tile = min(1024, T)
+    chunk = min(tile, max(256, m))
+
+    def kern(x_ref, a_ref, r_ref, b_ref, o_ref):
+        def body(k, carry):
+            s = pl.ds(pl.multiple_of(k * chunk, chunk), chunk)
+            y = jnp.take_along_axis(
+                x_ref[s, :], a_ref[s, :].astype(jnp.int32), axis=1)
+            y = _row_perm(y, r_ref[s, :].astype(jnp.int32), m)
+            o_ref[s, :] = jnp.take_along_axis(
+                y, b_ref[s, :].astype(jnp.int32), axis=1)
+            return carry
+
+        lax.fori_loop(0, tile // chunk, body, 0)
+
+    spec = pl.BlockSpec((tile, L), lambda i: (i, 0))
+    return pl.pallas_call(
+        kern,
+        out_shape=jax.ShapeDtypeStruct((T, L), x2d.dtype),
+        grid=(T // tile,),
+        name="clos_inner_level",
+        in_specs=[spec] * 4,
+        out_specs=spec,
+        interpret=interpret,
+    )(x2d, in2d, rows2d, out2d)
+
+
+def _inner_level(x, s_in, rows, s_out, m: int, pallas: bool):
+    """A route's innermost level over ``m``-slot sub-problems: its input
+    lane stage, its base stage in row-index form (:func:`_base_rows`)
+    and its output lane stage, on ``[rows, 128]`` tiles. The two
+    shuffles around the base stage never leave a block of ``m`` rows, so
+    they fold into the row permutation and no array with a minor
+    dimension below 128 is made. Scopes as in :func:`_lane_perm`."""
+    with jax.named_scope("clos.relayout"):
+        x2, a2, r2, b2 = (v.reshape(-1, 128) for v in (x, s_in, rows, s_out))
+    with jax.named_scope("clos.lane_perm"):
+        if pallas and x2.shape[0] >= max(32, m):
+            return _inner_level_pallas(x2, a2, r2, b2, m)
+        y = jnp.take_along_axis(x2, a2.astype(jnp.int32), axis=1)
+        y = jnp.take_along_axis(y.reshape(-1, m, 128),
+                                r2.reshape(-1, m, 128).astype(jnp.int32),
+                                axis=1)
+        return jnp.take_along_axis(y.reshape(-1, 128), b2.astype(jnp.int32),
+                                   axis=1)
+
+
 def route_core(x, stages, si: int, e_sub: int, bits: tuple, pallas: bool):
     """Apply a route program to ``x`` of length B·2^e_sub — B independent
     subproblems batched contiguously (every reshape/transpose below works
     on El-sized chunks, so subproblem boundaries are never crossed). The
     sharded executor (parallel/routed.py) uses B > 1 for the device-local
-    middle levels of a distributed route.
+    middle levels of a distributed route. ``stages`` is in
+    :func:`executor_stages` form.
 
-    The shuffles between stages run under the ``clos.relayout`` scope,
-    as do :func:`_lane_perm`'s reshapes; only the gathers are
-    ``clos.lane_perm``."""
+    Levels of 128-row sub-problems or more shuffle through HBM: the
+    perfect shuffle ``[B, m, 128] → [B, 128, m]`` moves 128 × 128 blocks
+    of the ``[B, m/128, 128, 128]`` view, so both of its sides keep
+    128-lane rows and it is one transpose, with no retiling copy after
+    it. A narrower innermost level (:func:`_fused_level`) runs its three
+    stages as one :func:`_inner_level`. The shuffles run under the
+    ``clos.relayout`` scope, as do the reshapes into the kernels' tiles;
+    only the kernels are ``clos.lane_perm``."""
     E = x.size
-    for li in range(len(bits) - 1):
+    fused = _fused_level(bits)
+    shuffled = len(bits) - 1 - fused
+    for li in range(shuffled):
         El = 1 << (e_sub - 7 * li)
         B, m = E // El, El >> 7
         x = _lane_perm(x, stages[si], pallas)
         with jax.named_scope("clos.relayout"):
-            x = x.reshape(B, m, 128).swapaxes(1, 2).reshape(E)
+            x = x.reshape(B, m // 128, 128, 128).transpose(0, 3, 1, 2)
+            x = x.reshape(E)
         si += 1
-    x = _lane_perm(x, stages[si], pallas)
+    if fused:
+        x = _inner_level(x, *stages[si: si + 3], 1 << bits[-1], pallas)
+        si += 3
+    else:
+        x = _lane_perm(x, stages[si], pallas)
+        si += 1
     with jax.named_scope("clos.relayout"):
         x = x.reshape(E)
-    si += 1
-    for li in reversed(range(len(bits) - 1)):
+    for li in reversed(range(shuffled)):
         El = 1 << (e_sub - 7 * li)
         B, m = E // El, El >> 7
         with jax.named_scope("clos.relayout"):
-            x = x.reshape(B, 128, m).swapaxes(1, 2).reshape(E)
+            x = x.reshape(B, 128, m // 128, 128).transpose(0, 2, 3, 1)
+            x = x.reshape(E)
         x = _lane_perm(x, stages[si], pallas)
         with jax.named_scope("clos.relayout"):
             x = x.reshape(E)
@@ -416,7 +552,10 @@ def apply_route(x, stages, e: int, bits: tuple, pallas: bool | None = None):
     """Route a device array through a plan: returns y with
     ``y[d] = x[perm[d]]``. ``stages`` is the tuple of flat uint8 device
     arrays from ``RoutePlan.stages``. Inside an outer jit, call
-    ``_apply_route_jit`` directly with a concrete ``pallas`` flag."""
+    ``_apply_route_jit`` directly with a concrete ``pallas`` flag and
+    the stages staged once by :func:`executor_stages`."""
     if pallas is None:
         pallas = _use_pallas()
-    return _apply_route_jit(x, tuple(stages), e, tuple(bits), pallas)
+    bits = tuple(bits)
+    return _apply_route_jit(x, executor_stages(stages, bits, "apply"), e,
+                            bits, pallas)

@@ -42,7 +42,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .clos import _apply_route_jit, _use_pallas, plan_route, plan_routes
+from .clos import (
+    _apply_route_jit,
+    _use_pallas,
+    executor_stages,
+    plan_route,
+    plan_routes,
+)
 from .converge import (
     Semiring,
     adaptive_loop,
@@ -736,8 +742,11 @@ def routed_arrays(op: RoutedOperator, dtype=jnp.float32, alpha: float = 0.0,
             jnp.asarray(_expand_matrix(w, np.float32), dtype=dtype)
             if w < 128 else None
             for w in op.in_widths),
-        "edge_stages": tuple(jnp.asarray(s) for s in op.edge_stages),
-        "state_stages": tuple(jnp.asarray(s) for s in op.state_stages),
+        "edge_stages": executor_stages(
+            (jnp.asarray(s) for s in op.edge_stages), op.edge_bits, "edge"),
+        "state_stages": executor_stages(
+            (jnp.asarray(s) for s in op.state_stages), op.state_bits,
+            "state"),
         "valid": jnp.asarray(op.valid, dtype=dtype),
         "dangling": jnp.asarray(op.dangling, dtype=dtype),
         "n_valid": jnp.asarray(float(op.n_valid), dtype=dtype),

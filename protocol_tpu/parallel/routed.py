@@ -40,7 +40,13 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..graph import filter_edges
-from ..ops.clos import _lane_perm, _use_pallas, plan_route, route_core
+from ..ops.clos import (
+    _lane_perm,
+    _use_pallas,
+    executor_stages,
+    plan_route,
+    route_core,
+)
 from ..ops.routed import (
     _bucketize_blocked,
     _ceil_pow2_exp,
@@ -163,6 +169,16 @@ class ShardedRoutedOperator:
         D = self.num_shards
         if pretrust is None:
             pretrust = self.valid.astype(np.float64) / max(self.n_valid, 1)
+
+        def stages(flat, bits, route):
+            # the local route_core runs bits[1:] from stage 1 when the
+            # level-0 shuffle crosses the mesh (sharded_apply_route)
+            si, local = ((0, bits) if D == 1 or len(bits) == 1
+                         else (1, bits[1:]))
+            staged = executor_stages((jnp.asarray(s) for s in flat), local,
+                                     route, si=si)
+            return tuple(s.reshape(D, -1) for s in staged)
+
         return {
             "out_weight": tuple(jnp.asarray(w, dtype=dtype)
                                 for w in self.out_weight),
@@ -178,10 +194,9 @@ class ShardedRoutedOperator:
                                     (D, 128 // w, 128)).copy(), dtype=dtype)
                 if w < 128 else jnp.zeros((D, 1, 1), dtype=dtype)
                 for w in self.in_widths),
-            "edge_stages": tuple(
-                jnp.asarray(s.reshape(D, -1)) for s in self.edge_stages),
-            "state_stages": tuple(
-                jnp.asarray(s.reshape(D, -1)) for s in self.state_stages),
+            "edge_stages": stages(self.edge_stages, self.edge_bits, "edge"),
+            "state_stages": stages(self.state_stages, self.state_bits,
+                                   "state"),
             "valid": jnp.asarray(
                 self.valid.reshape(D, -1), dtype=dtype),
             "dangling": jnp.asarray(

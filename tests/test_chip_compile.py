@@ -69,6 +69,25 @@ def test_clos_lane_gather_compiles(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("rows,m", [(524_288, 32), (32_768, 2), (4_096, 64)],
+                         ids=["s21-edge", "s21-state", "widest"])
+def test_clos_inner_level_compiles(one_chip, rows, m):
+    """The fused inner-level kernel at the Graph500 scale-21 routes'
+    innermost levels (2^26 slots in 32-row blocks, 2^22 in 2-row
+    blocks) and at the widest block a narrow level has (64 rows)."""
+    import jax
+    import jax.numpy as jnp
+
+    from protocol_tpu.ops.clos import _inner_level_pallas
+
+    x = jax.ShapeDtypeStruct((rows, 128), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((rows, 128), jnp.uint8, sharding=one_chip)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda *a: _inner_level_pallas(*a, m)).lower(
+            x, idx, idx, idx).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 def test_converge_routed_adaptive_compiles(one_chip):
     """The whole routed converge program on a 65,536-peer BA operator,
     with the Pallas route forced (``_use_pallas`` would see the CPU)."""

@@ -13,11 +13,15 @@ jnp = pytest.importorskip("jax.numpy")
 
 from protocol_tpu.graph import barabasi_albert_edges, build_operator
 from protocol_tpu.ops.clos import (
+    _base_rows,
+    _inner_level_pallas,
     apply_route,
     apply_route_np,
+    executor_stages,
     plan_route,
     plan_route_py,
     route_bits,
+    route_core,
 )
 from protocol_tpu.ops.converge import converge_sparse_adaptive, operator_arrays, spmv
 from protocol_tpu.ops.routed import (
@@ -82,16 +86,117 @@ def test_planner_requires_pow2():
         plan_route_py(np.arange(64))
 
 
-@pytest.mark.parametrize("e", [7, 12, 15])
-def test_device_executor_matches_numpy(e):
-    rng = np.random.default_rng(7 * e)
-    E = 1 << e
-    perm = rng.permutation(E)
-    plan = plan_route(perm)
-    x = rng.standard_normal(E).astype(np.float32)
-    stages = tuple(jnp.asarray(s) for s in plan.stages)
-    y = np.asarray(apply_route(jnp.asarray(x), stages, plan.e, plan.bits))
-    assert np.array_equal(y, x[perm])
+def _batched_plans(e_sub: int, batch: int, rng):
+    """``batch`` independent routes of 2^e_sub slots, their stages laid
+    end to end as the sharded executor's local middle levels see them."""
+    perms = [rng.permutation(1 << e_sub) for _ in range(batch)]
+    plans = [plan_route(p) for p in perms]
+    stages = [np.concatenate([pl.stages[k] for pl in plans])
+              for k in range(len(plans[0].stages))]
+    return perms, plans[0].bits, stages
+
+
+# e = 7 is base only; 8..14 are two levels with every innermost width
+# bits[-1] from 1 to 7; 15 and 16 are three levels. "-B<n>" cases run
+# route_core on n sub-problems at once, as parallel/routed.py does.
+@pytest.mark.parametrize("e,batch", [
+    pytest.param(e, 1, id=str(e)) for e in range(7, 17)
+] + [
+    pytest.param(e, 4, id=f"{e}-B4") for e in (9, 14, 15)
+])
+def test_device_executor_matches_numpy(e, batch):
+    import jax
+
+    rng = np.random.default_rng(7 * e + batch)
+    if batch == 1:
+        E = 1 << e
+        perm = rng.permutation(E)
+        plan = plan_route(perm)
+        x = rng.standard_normal(E).astype(np.float32)
+        stages = tuple(jnp.asarray(s) for s in plan.stages)
+        y = np.asarray(apply_route(jnp.asarray(x), stages, plan.e, plan.bits))
+        assert np.array_equal(y, x[perm])
+        return
+    perms, bits, stages = _batched_plans(e, batch, rng)
+    x = rng.standard_normal(batch << e).astype(np.float32)
+    staged = executor_stages([jnp.asarray(s) for s in stages], bits, "test")
+    y = np.asarray(jax.jit(
+        lambda x, st: route_core(x, st, 0, e, bits, False))(
+            jnp.asarray(x), staged))
+    want = np.concatenate([x[b << e:(b + 1) << e][p]
+                           for b, p in enumerate(perms)])
+    assert np.array_equal(y, want)
+
+
+@pytest.mark.parametrize("b,batch", [(1, 256), (3, 64), (5, 16)],
+                         ids=["m2", "m8", "m32"])
+def test_inner_level_kernel_matches_numpy(b, batch):
+    """The fused inner-level Pallas kernel, interpreted on the CPU, over
+    two-level routes (input lane stage, base, output lane stage: the
+    whole route is its inner level) against the host executor: 512 rows,
+    two chunks of the kernel's walk."""
+    rng = np.random.default_rng(b)
+    e = 7 + b
+    perms = [rng.permutation(1 << e) for _ in range(batch)]
+    plans = [plan_route_py(p) for p in perms]
+    s_in, base, s_out = (np.concatenate([pl.stages[k] for pl in plans])
+                         for k in range(3))
+    x = rng.standard_normal(batch << e).astype(np.float32)
+    rows = _base_rows(jnp.asarray(base), 1 << b)
+    y = _inner_level_pallas(
+        *(jnp.asarray(v).reshape(-1, 128) for v in (x, s_in, rows, s_out)),
+        1 << b, interpret=True)
+    want = np.concatenate([apply_route_np(pl, x[k << e:(k + 1) << e])
+                           for k, pl in enumerate(plans)])
+    assert np.array_equal(np.asarray(y).reshape(-1), want)
+
+
+@pytest.mark.parametrize("b", range(1, 7))
+def test_base_rows_permute_within_each_block_and_lane(b):
+    """A base stage's row-index form names every row of its m-row block
+    once in every lane: a permutation per (block, lane)."""
+    m = 1 << b
+    rng = np.random.default_rng(30 + b)
+    plan = plan_route(rng.permutation(1 << (14 + b)))
+    assert plan.bits[-1] == b
+    rows = np.asarray(_base_rows(jnp.asarray(plan.stages[len(plan.bits) - 1]),
+                                 m)).reshape(-1, m, 128)
+    assert np.array_equal(np.sort(rows, axis=1),
+                          np.broadcast_to(np.arange(m)[:, None], rows.shape))
+
+
+@pytest.fixture()
+def tracer():
+    from protocol_tpu.utils import trace
+
+    trace.TRACER.reset_instruments()
+    was_enabled = trace.TRACER.enabled
+    trace.TRACER.enable()
+    yield trace
+    trace.TRACER.reset_instruments()
+    if not was_enabled:
+        trace.TRACER.disable()
+
+
+@pytest.mark.parametrize("e,fused", [(7, 0), (8, 1), (13, 1), (14, 0),
+                                     (15, 1)])
+def test_inner_fused_counter_per_staged_route(tracer, e, fused):
+    """``clos_inner_fused`` counts a staged route once where its
+    innermost level is narrower than 128 (bits[-1] < 7), never for a
+    lane-dense innermost level or a base-only route."""
+    plan = plan_route(np.random.default_rng(e).permutation(1 << e))
+    executor_stages(plan.stages, plan.bits, "edge")
+    assert tracer.counter_total("clos_inner_fused", route="edge") == fused
+
+
+def test_inner_fused_counter_per_staged_operator(tracer):
+    n, m = 1500, 5
+    src, dst, val = barabasi_albert_edges(n, m, seed=22)
+    rop = build_routed_operator(n, src, dst, val)
+    routed_arrays(rop, dtype=jnp.float32, alpha=0.1)
+    for route, bits in (("edge", rop.edge_bits), ("state", rop.state_bits)):
+        want = int(len(bits) > 1 and bits[-1] < 7)
+        assert tracer.counter_total("clos_inner_fused", route=route) == want
 
 
 def test_identity_route_is_identity():
