@@ -75,7 +75,7 @@ def _default_shard_glv() -> bool:
     CPU meshes — a compile-correctness harness, never a throughput
     claim — the default therefore shard_maps only the cheap prep stage
     and runs the ladder through the single-device program.
-    ``tests/test_ingest.py`` keeps the full sharded ladder
+    ``tests/test_ingest_sharded.py`` keeps the full sharded ladder
     suite-covered via an explicit ``shard_glv=True``; the multichip
     dryrun's CPU form goes further and checks prep-stage parity only
     (``sharded_prep_parity``) because even the single-device ladder
@@ -124,7 +124,7 @@ def sharded_recover_batch(rs, ss, rec_ids, msgs, mesh: Mesh,
     """``ops.secp_batch.recover_batch`` with the device stages sharded
     over ``mesh``'s lane axis — same host orchestration, same outputs
     (bit-identical; asserted by the multichip dryrun and
-    ``tests/test_ingest.py``). The lane count must divide the mesh.
+    ``tests/test_ingest_sharded.py``). The lane count must divide the mesh.
 
     ``shard_glv=None`` follows :func:`_default_shard_glv`: on XLA:CPU the
     GLV ladder stage runs the single-device program (its shard_mapped

@@ -9,9 +9,16 @@ every subprocess a test starts). The persistent compile cache stays off
 rule honors) unless PTPU_TEST_XLA_CACHE=1 opts in below.
 float64 is enabled globally: parity tests compare against the exact
 rational oracle at f64 precision (the TPU bench path stays f32).
+
+Every test runs under a watchdog (``pytest_runtest_protocol`` below).
 """
 
+import faulthandler
+import hashlib
 import os
+from pathlib import Path
+
+import pytest
 
 # PTPU_TPU=1 skips the CPU pin so the session runs against the real TPU
 # chip. It is meant ONLY for the device-prover battery —
@@ -84,6 +91,113 @@ if os.environ.get("PTPU_TEST_XLA_CACHE") == "1":
                           2.0)
     except Exception:  # cache is an optimization, never a failure
         pass
+
+
+# --- per-test watchdog -----------------------------------------------------
+# Each test (setup, call and teardown) gets WATCHDOG_S seconds, or what
+# its @pytest.mark.watchdog(seconds) says. Past that, faulthandler's own
+# thread dumps every thread's stack to the session's stderr and ends the
+# process; it fires even while the test is inside native code (an XLA
+# compile), where no Python signal handler runs. Under xdist the
+# controller reports the test failed by its name ("worker ... crashed
+# while running ...") and starts a fresh worker for the rest. With
+# --dist loadfile xdist hands the dead test to that worker again along
+# with the rest of its file; the marker a test leaves in the session's
+# shared basetemp while it runs turns that second start into a no-op,
+# so the test is reported once, as failed. The limit is a hang guard,
+# not a speed gate: the slowest tier-1 test takes five minutes on an
+# 8-core host. A `slow` test that can run longer carries a marker of
+# about three times its time measured there.
+
+WATCHDOG_S = 600.0
+_STACKS = pytest.StashKey()  # stderr as it was before output capture
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "watchdog(seconds): this test's watchdog limit, "
+        f"in place of the default {WATCHDOG_S:.0f} s")
+    config.stash[_STACKS] = os.fdopen(os.dup(2), "w")
+
+
+def pytest_unconfigure(config):
+    stacks = config.stash.get(_STACKS, None)
+    if stacks is not None:
+        stacks.close()
+
+
+def _running_marker(item):
+    """The file that says ``item`` is running in this xdist session, in
+    the basetemp its workers share; None outside xdist (and for remote
+    workers, which get no basetemp)."""
+    basetemp = item.config.option.basetemp
+    if not hasattr(item.config, "workerinput") or basetemp is None:
+        return None
+    digest = hashlib.sha1(item.nodeid.encode()).hexdigest()
+    return Path(basetemp).parent / f"running-{digest}"
+
+
+@pytest.hookimpl(tryfirst=True, specname="pytest_runtest_protocol")
+def pytest_runtest_protocol_once(item, nextitem):
+    """Runs before the runner; a second ``pytest_runtest_protocol``
+    implementation (pytest takes only ``pytest_*`` names as hooks)."""
+    marker = _running_marker(item)
+    if marker is None:
+        return None
+    if marker.exists():
+        return True  # its worker died in it; xdist reported it failed
+    marker.touch()
+    return None
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    mark = item.get_closest_marker("watchdog")
+    faulthandler.dump_traceback_later(
+        mark.args[0] if mark else WATCHDOG_S, exit=True,
+        file=item.config.stash[_STACKS])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        marker = _running_marker(item)
+        if marker is not None:
+            marker.unlink(missing_ok=True)
+
+
+# --- xdist: the longest files first ---------------------------------------
+# `--dist loadfile` hands out files in order of their test count, so a
+# long file of one or two tests started last and set the wall time.
+# These files start first, longest first (seconds of test time each, on
+# an 8-core host under -n 6); every other file follows in xdist's order.
+# The list only orders the run: a file missing from it still runs.
+
+FIRST_FILES = (
+    "tests/test_secp_batch.py",               # ~450 s
+    "tests/test_ingest.py",                   # ~450 s
+    "tests/test_ingest_sharded.py",           # ~340 s
+    "tests/test_pool.py",                     # ~170 s
+    "tests/test_prover_tpu.py",               # ~140 s
+    "tests/test_parallel_prover.py",          # ~120 s
+    "tests/test_invariance.py",               # ~120 s
+    "tests/test_prover_tpu_alternation.py",   # ~110 s
+)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class LongestFirst(LoadFileScheduling):
+        def _assign_work_unit(self, node):
+            for scope in reversed(FIRST_FILES):
+                if scope in self.workqueue:
+                    self.workqueue.move_to_end(scope, last=False)
+            super()._assign_work_unit(node)
+
+    return LongestFirst(config, log)
 
 
 def make_signed_attestation(kp, about: bytes, domain: bytes, value: int,

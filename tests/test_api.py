@@ -114,6 +114,7 @@ class TestApiFast:
 
 
 @pytest.mark.slow
+@pytest.mark.watchdog(1500)  # k=20 keygen + prove in setup: 396–480 s
 class TestApiProveCycle:
     """Full byte-artifact cycle at the tiny real shape. Key structural
     property under test: the proving key generated over the *dummy*
@@ -178,6 +179,7 @@ class TestApiThresholdCycle:
     KZG decider on the accumulator limbs. The reference #[ignore]s the
     same flow (threshold/mod.rs:850,951)."""
 
+    @pytest.mark.watchdog(7200)  # 2,303 s on an 8-core CPU host
     def test_th_cycle(self):
         # k=21 — the reference's own Threshold KZG degree
         # (circuits/mod.rs:59): the batched-MSM verifier fold brought

@@ -496,6 +496,7 @@ class TestEvmVerifierVerb:
 
 
 @pytest.mark.slow
+@pytest.mark.watchdog(1200)  # k=20 SRS, keygen and prove: 397 s
 def test_cli_keccak_onchain_flow_tiny(tmp_path, capsys, monkeypatch):
     """The judge-facing end-to-end: attest -> local-scores -> kzg-params
     -> et-proving-key -> et-proof --transcript keccak -> et-verifier

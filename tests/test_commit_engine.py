@@ -287,19 +287,22 @@ def test_sharded_flush_surfaces_unit_errors():
 
 # --- byte-identical proofs, engine on vs off -------------------------------
 
-def _tiny_circuit():
+@pytest.fixture(scope="module")
+def tiny():
+    """One tiny circuit, its k=7 params and its eval-form key, shared by
+    the proving tests below (one set of prover programs per file)."""
     from protocol_tpu.cli.profilecmd import synthetic_circuit
-
-    return synthetic_circuit(gates=24, seed=5, lookup_row=True)
-
-
-def test_engine_on_off_proofs_identical_host(monkeypatch):
     from protocol_tpu.zk import prover_fast as pf
+
+    cs = synthetic_circuit(gates=24, seed=5, lookup_row=True)
+    params = pf.setup_params_fast(7, seed=b"engine-parity")
+    return pf, params, pf.keygen_fast(params, cs, k=7, eval_pk=True), cs
+
+
+def test_engine_on_off_proofs_identical_host(tiny, monkeypatch):
     from protocol_tpu.zk.plonk import verify
 
-    cs = _tiny_circuit()
-    params = pf.setup_params_fast(7, seed=b"engine-parity")
-    pk = pf.keygen_fast(params, cs, k=7, eval_pk="auto")
+    pf, params, pk, cs = tiny
     monkeypatch.delenv("PTPU_COMMIT_ENGINE", raising=False)
     on = pf.prove_fast(params, pk, cs, randint=lambda: 424242)
     monkeypatch.setenv("PTPU_COMMIT_ENGINE", "0")
@@ -308,14 +311,11 @@ def test_engine_on_off_proofs_identical_host(monkeypatch):
     assert verify(params, pk, cs.public_values(), on)
 
 
-def test_engine_on_off_proofs_identical_tpu(monkeypatch):
+def test_engine_on_off_proofs_identical_tpu(tiny, monkeypatch):
     pytest.importorskip("jax")
-    from protocol_tpu.zk import prover_fast as pf
     from protocol_tpu.zk.plonk import verify
 
-    cs = _tiny_circuit()
-    params = pf.setup_params_fast(7, seed=b"engine-parity-tpu")
-    pk = pf.keygen_fast(params, cs, k=7, eval_pk=True)
+    pf, params, pk, cs = tiny
     monkeypatch.delenv("PTPU_COMMIT_ENGINE", raising=False)
     on = pf.prove_fast_tpu(params, pk, cs, randint=lambda: 171717)
     monkeypatch.setenv("PTPU_COMMIT_ENGINE", "0")
@@ -327,16 +327,12 @@ def test_engine_on_off_proofs_identical_tpu(monkeypatch):
 
 # --- observability ----------------------------------------------------------
 
-def test_commit_stages_and_batch_histogram(monkeypatch):
+def test_commit_stages_and_batch_histogram(tiny, monkeypatch):
     """A host prove lands commit.* stage series carrying the batched
     label and populates ptpu_commit_batch_size with widths > 1 (the
     r1 batch is 7 same-bases columns)."""
-    from protocol_tpu.zk import prover_fast as pf
-
+    pf, params, pk, cs = tiny
     monkeypatch.delenv("PTPU_COMMIT_ENGINE", raising=False)
-    cs = _tiny_circuit()
-    params = pf.setup_params_fast(7, seed=b"engine-metrics")
-    pk = pf.keygen_fast(params, cs, k=7, eval_pk="auto")
     trace.enable()
     trace.TRACER.reset_instruments()
     try:

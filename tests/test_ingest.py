@@ -85,89 +85,6 @@ class TestBatchedIngest:
         assert v_honest.all()
 
 
-class TestShardedIngest:
-    def test_lane_sharded_recovery_bit_identical(self):
-        """parallel/ingest.py over the virtual 8-device mesh: outputs
-        must be bit-identical to the single-device path, with the
-        binding checks agreeing lane for lane (the driver's
-        dryrun_multichip runs the same check; this keeps it in the
-        battery)."""
-        import random
-
-        import jax
-        from jax.sharding import Mesh
-        import numpy as np
-
-        from protocol_tpu.crypto.secp256k1 import EcdsaKeypair
-        from protocol_tpu.ops.secp_batch import SECP_N, recover_batch
-        from protocol_tpu.parallel.ingest import sharded_recover_batch
-
-        if jax.device_count() < 8:
-            pytest.skip("needs the 8-device virtual mesh (conftest)")
-        ndev = 8
-        mesh = Mesh(np.array(jax.devices()[:ndev]), ("lanes",))
-        rng = random.Random(0xB00)
-        k = 16
-        kps = [EcdsaKeypair(61_000 + i) for i in range(k)]
-        msgs = [rng.randrange(1, SECP_N) for _ in range(k)]
-        sigs = [kp.sign(m) for kp, m in zip(kps, msgs)]
-        rs = [s.r for s in sigs]
-        ss = [s.s for s in sigs]
-        recs = [s.rec_id for s in sigs]
-        ss[3] = 0  # binding-check reject must survive the sharding
-        xs0, ys0, v0 = recover_batch(rs, ss, recs, msgs)
-        # shard_glv=True forces the FULL sharded ladder even on the CPU
-        # mesh (the default trims it there for compile budget): this is
-        # the committed coverage of the sharded GLV stage
-        xs1, ys1, v1 = sharded_recover_batch(rs, ss, recs, msgs, mesh,
-                                             shard_glv=True)
-        assert (v0 == v1).all() and not v1[3] and v1.sum() == k - 1
-        assert xs0 == xs1 and ys0 == ys1
-
-    def test_dryrun_ingest_stage_within_cpu_budget(self):
-        """Timing guard for the driver's multichip ingest stage: the
-        r5 regression was minutes-long GLV-ladder XLA:CPU compiles
-        timing out the whole dryrun (MULTICHIP_r05.json rc=124). The
-        stage's CPU form (prep-stage parity, no ladder) must stay
-        inside a small fraction of the driver budget — if this starts
-        failing, a minutes-long compile crept back into the dryrun."""
-        import time
-
-        import jax
-
-        if jax.device_count() < 8:
-            pytest.skip("needs the 8-device virtual mesh (conftest)")
-        import __graft_entry__ as graft
-        from protocol_tpu.parallel import make_mesh
-
-        mesh = make_mesh(8)
-        t0 = time.monotonic()
-        graft._dryrun_sharded_ingest(8, mesh)
-        wall = time.monotonic() - t0
-        budget = float(
-            __import__("os").environ.get("PTPU_DRYRUN_INGEST_BUDGET_S",
-                                         "600"))
-        assert wall < budget, (
-            f"dryrun ingest stage took {wall:.0f}s (> {budget:.0f}s): "
-            "a minutes-long XLA:CPU compile is back on the dryrun path")
-
-    def test_indivisible_lane_count_rejected(self):
-        import jax
-        from jax.sharding import Mesh
-        import numpy as np
-
-        from protocol_tpu.parallel.ingest import sharded_recover_batch
-
-        ndev = min(8, jax.device_count())
-        if ndev < 2:
-            pytest.skip("needs a multi-device mesh")
-        mesh = Mesh(np.array(jax.devices()[:ndev]), ("lanes",))
-        with pytest.raises(ValueError):
-            sharded_recover_batch([1] * (ndev + 1), [1] * (ndev + 1),
-                                  [0] * (ndev + 1), [1] * (ndev + 1),
-                                  mesh)
-
-
 class TestClientBatchedIngest:
     def test_et_setup_identical_between_paths(self):
         """Client(batched_ingest=True) must produce the same ETSetup as
@@ -209,13 +126,31 @@ class TestClientBatchedIngest:
 class TestChunkedPipelineIngest:
     """Above the lane cap (PTPU_INGEST_CHUNK) the product path chunks
     and software-pipelines; results must be identical to the
-    single-batch path, including validity masks and full-verify."""
+    single-batch path, including validity masks and full-verify.
+
+    The reference is the single-batch path run on each chunk's lanes
+    (lanes are independent), so that 21–22 lanes under a cap of 8 run
+    every ladder at bucket 8, the one the rest of the file compiles:
+    three chunks put the pipeline's steady state (a chunk submitted
+    while the one before it is finalized) between its prologue and
+    epilogue, and the short last chunk of 5–6 lanes still pads to 8."""
+
+    CAP = 8
+
+    def _single_batch_per_chunk(self, atts):
+        pks, addrs, valid = [], [], []
+        for lo in range(0, len(atts), self.CAP):
+            p, a, v = recover_signers_batch(atts[lo:lo + self.CAP])
+            pks += p
+            addrs += a
+            valid.append(v)
+        return pks, addrs, np.concatenate(valid)
 
     def test_chunked_matches_single_batch(self, batch, monkeypatch):
         _, signed = batch
-        many = (signed * 3)[:14]  # 14 lanes, cap 4 → 4 chunks, last short
-        ref_pks, ref_addrs, ref_valid = recover_signers_batch(many)
-        monkeypatch.setenv("PTPU_INGEST_CHUNK", "4")
+        many = (signed * 5)[:22]  # cap 8 → [0-7][8-15][16-21]
+        ref_pks, ref_addrs, ref_valid = self._single_batch_per_chunk(many)
+        monkeypatch.setenv("PTPU_INGEST_CHUNK", str(self.CAP))
         pks, addrs, valid = recover_signers_batch(many)
         assert (valid == ref_valid).all()
         assert addrs == ref_addrs
@@ -226,25 +161,25 @@ class TestChunkedPipelineIngest:
 
     def test_chunked_flags_forged_lane(self, batch, monkeypatch):
         kps, signed = batch
-        many = list(signed * 2)
+        many = (signed * 5)[:21]
         # signature from key 0 pasted onto a different attestation.
-        # Lane 9 (second copy of lane 4) lands in the SHORT trailing
-        # chunk (10 lanes, cap 4 → [0-3][4-7][8-9]) — the padded-chunk
-        # boundary case
-        many[9] = SignedAttestationData(many[9].attestation,
-                                        signed[0].signature)
-        ref_pks, ref_addrs, ref_valid = recover_signers_batch(many)
-        monkeypatch.setenv("PTPU_INGEST_CHUNK", "4")
+        # Lane 19 (fourth copy of lane 4) lands in the SHORT trailing
+        # chunk (21 lanes, cap 8 → [0-7][8-15][16-20]) — the
+        # padded-chunk boundary case
+        many[19] = SignedAttestationData(many[19].attestation,
+                                         signed[0].signature)
+        ref_pks, ref_addrs, ref_valid = self._single_batch_per_chunk(many)
+        monkeypatch.setenv("PTPU_INGEST_CHUNK", str(self.CAP))
         pks, addrs, valid = recover_signers_batch(many)
         assert (valid == ref_valid).all()
         assert addrs == ref_addrs
-        if valid[9]:
-            assert addrs[9] != kps[4].public_key.to_address_bytes()
+        if valid[19]:
+            assert addrs[19] != kps[4].public_key.to_address_bytes()
 
     def test_chunked_full_verify_mask_stable(self, batch, monkeypatch):
         _, signed = batch
-        many = signed * 2
-        monkeypatch.setenv("PTPU_INGEST_CHUNK", "4")
+        many = (signed * 5)[:21]  # cap 8 → [0-7][8-15][16-20]
+        monkeypatch.setenv("PTPU_INGEST_CHUNK", str(self.CAP))
         _, _, base = recover_signers_batch(many)
         _, _, audited = recover_signers_batch(many, full_verify=True)
         assert (base == audited).all()

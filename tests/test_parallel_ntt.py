@@ -18,6 +18,11 @@ pytestmark = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs the virtual 8-device mesh"
 )
 
+# one compiled program per (plan, mesh): called outside jit, shard_map
+# runs its body primitive by primitive over the mesh, which takes some
+# 110 s a call on an 8-device XLA:CPU mesh against ~3 s compiled
+_sharded = jax.jit(ntt_sharded, static_argnums=(1, 2))
+
 
 def _rand_planes(n, seed):
     rng = np.random.default_rng(seed)
@@ -26,14 +31,25 @@ def _rand_planes(n, seed):
     return jnp.asarray(f2.ints_to_planes(mont))
 
 
+@pytest.fixture(scope="module")
+def single_device():
+    """k -> (input, single-device NTT of it), each computed once."""
+    cache = {}
+
+    def get(k):
+        if k not in cache:
+            x = _rand_planes(1 << k, 100 + k)
+            cache[k] = x, np.asarray(ntt_tpu.ntt(x, ntt_tpu.NttPlan.get(k)))
+        return cache[k]
+
+    return get
+
+
 @pytest.mark.parametrize("k,shards", [(10, 8), (10, 2), (8, 4)])
-def test_sharded_ntt_bit_exact(k, shards):
-    n = 1 << k
-    plan = ntt_tpu.NttPlan.get(k)
-    x = _rand_planes(n, 100 + k)
-    expect = np.asarray(ntt_tpu.ntt(x, plan))
-    mesh = make_mesh(shards)
-    got = np.asarray(ntt_sharded(x, plan, mesh))
+def test_sharded_ntt_bit_exact(single_device, k, shards):
+    x, expect = single_device(k)
+    got = np.asarray(_sharded(x, ntt_tpu.NttPlan.get(k),
+                              make_mesh(shards)))
     assert np.array_equal(got, expect)
 
 
@@ -42,9 +58,9 @@ def test_sharded_ntt_rejects_bad_shard_count():
     mesh = make_mesh(8)
     x = _rand_planes(1 << 8, 1)
     # fine: 16 % 8 == 0; then check a non-dividing count via a fake
-    got = ntt_sharded(x, plan, mesh)
+    got = _sharded(x, plan, mesh)
     assert got.shape == (f2.L, 1 << 8)
     plan6 = ntt_tpu.NttPlan.get(6)  # B = 8, A = 8
     mesh3 = make_mesh(3)
     with pytest.raises(ValueError):
-        ntt_sharded(_rand_planes(1 << 6, 2), plan6, mesh3)
+        _sharded(_rand_planes(1 << 6, 2), plan6, mesh3)

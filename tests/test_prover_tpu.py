@@ -148,17 +148,17 @@ def test_barycentric_eval(dp):
     assert dp_obj.eval_at(dev, zeta) == int(expect)
 
 
-def test_prove_fast_tpu_bytes_equal_host():
-    """End-to-end transcript lockstep: for the same blinding stream the
-    integrated device prover must emit BYTE-IDENTICAL proofs to the host
-    prover (prover_fast.py's LOCKSTEP WARNING, enforced). Runs on every
-    backend — this is the test that makes an absorb-order divergence
-    between the two provers fail CI instead of merging green."""
+@pytest.fixture(scope="module")
+def tiny():
+    """(params, eval-form pk, cs) of the lockstep circuit (a lookup row
+    and a copy among its gates) at k = K: every full prove in this file
+    runs at that one domain size, so the device prover's programs
+    compile once."""
     import random
 
     from protocol_tpu.utils.fields import BN254_FR_MODULUS as R
     from protocol_tpu.zk import prover_fast as pf
-    from protocol_tpu.zk.plonk import ConstraintSystem, verify
+    from protocol_tpu.zk.plonk import ConstraintSystem
 
     rng = random.Random(11)
     cs = ConstraintSystem(lookup_bits=6)
@@ -170,9 +170,23 @@ def test_prove_fast_tpu_bytes_equal_host():
     cs.copy(lk, (0, row))
     cs.public_input(777)
     cs.check_satisfied()
+    params = pf.setup_params_fast(K, seed=b"lockstep")
+    return params, pf.keygen_fast(params, cs, k=K, eval_pk=True), cs
 
-    params = pf.setup_params_fast(6, seed=b"lockstep")
-    pk = pf.keygen_fast(params, cs, eval_pk=True)
+
+def test_prove_fast_tpu_bytes_equal_host(tiny):
+    """End-to-end transcript lockstep: for the same blinding stream the
+    integrated device prover must emit BYTE-IDENTICAL proofs to the host
+    prover (prover_fast.py's LOCKSTEP WARNING, enforced). Runs on every
+    backend — this is the test that makes an absorb-order divergence
+    between the two provers fail CI instead of merging green."""
+    import random
+
+    from protocol_tpu.utils.fields import BN254_FR_MODULUS as R
+    from protocol_tpu.zk import prover_fast as pf
+    from protocol_tpu.zk.plonk import verify
+
+    params, pk, cs = tiny
     r1, r2 = random.Random(42), random.Random(42)
     proof_tpu = pf.prove_fast_tpu(params, pk, cs,
                                   randint=lambda: r1.randrange(R))
@@ -259,7 +273,7 @@ def test_fused_quotient_latched_and_fused_intt_warns(dp, monkeypatch):
     del dp2, dp3
 
 
-def test_prove_streaming_mode_bytes_equal_host(monkeypatch):
+def test_prove_streaming_mode_bytes_equal_host(tiny, monkeypatch):
     """Full prove_fast_tpu in streaming (k≥21-style) mode — packed
     coefficient arrays, on-the-fly pk ext chunks, packed t chunks,
     fused quotient AND the opt-in fused 4n inverse — must still emit
@@ -270,22 +284,9 @@ def test_prove_streaming_mode_bytes_equal_host(monkeypatch):
 
     from protocol_tpu.utils.fields import BN254_FR_MODULUS as R
     from protocol_tpu.zk import prover_fast as pf
-    from protocol_tpu.zk.plonk import (
-        FIXED_NAMES,
-        NUM_WIRES,
-        ConstraintSystem,
-        verify,
-    )
+    from protocol_tpu.zk.plonk import FIXED_NAMES, NUM_WIRES, verify
 
-    rng = random.Random(21)
-    cs = ConstraintSystem(lookup_bits=6)
-    for _ in range(16):
-        a, b = rng.randrange(50), rng.randrange(50)
-        cs.add_row([a, b, (a * b + a) % R], q_a=1, q_mul_ab=1, q_c=R - 1)
-    cs.public_input(31337)
-    cs.check_satisfied()
-    params = pf.setup_params_fast(6, seed=b"stream-lock")
-    pk = pf.keygen_fast(params, cs, eval_pk=True)
+    params, pk, cs = tiny
     ext_n = (1 << pk.k) * 4
     shift = _find_coset_shifts(ext_n, 2)[1]
     dp_stream = ptpu.DeviceProver(
@@ -388,74 +389,3 @@ def test_quotient_chunk_matches_host(dp):
             pi_dev[j], [u[j] for u in uv_dev], ch_planes))
     got = _chunks_to_host_order(dp_obj, t_dev)
     assert np.array_equal(got, t_host)
-
-
-def test_device_prover_cache_alternation(monkeypatch):
-    """The Threshold cycle's access pattern: two pks alternating proves
-    in one process. The MRU cache must keep BOTH DeviceProvers alive
-    (identity-stable across the alternation — no re-init), suspend the
-    inactive one, and resume must rebuild bit-identical state: every
-    proof stays byte-equal to the host prover. Also covers deep
-    suspend (static tables dropped and rebuilt)."""
-    import random
-
-    # pin the knobs the asserts depend on (a measurement environment
-    # may export the single-slot fallback)
-    monkeypatch.setenv("PTPU_DP_CACHE", "2")
-    monkeypatch.delenv("PTPU_DP_SUSPEND", raising=False)
-
-    from protocol_tpu.utils.fields import BN254_FR_MODULUS as R
-    from protocol_tpu.zk import prover_fast as pf
-    from protocol_tpu.zk.plonk import ConstraintSystem, verify
-
-    def mk(seed, rows, k):
-        rng = random.Random(seed)
-        cs = ConstraintSystem(lookup_bits=6)
-        for _ in range(rows):
-            a, b = rng.randrange(50), rng.randrange(50)
-            cs.add_row([a, b, (a * b + a) % R], q_a=1, q_mul_ab=1,
-                       q_c=R - 1)
-        cs.public_input(seed)
-        cs.check_satisfied()
-        params = pf.setup_params_fast(k, seed=b"dpcache%d" % seed)
-        return params, pf.keygen_fast(params, cs, k=k, eval_pk=True), cs
-
-    pa = mk(7, 20, 6)
-    pb = mk(8, 40, 7)
-
-    pf._DEVICE_PROVERS.clear()
-    try:
-        seen = {}
-        for rnd, (params, pk, cs) in enumerate((pa, pb, pa, pb, pa)):
-            r1, r2 = random.Random(90 + rnd), random.Random(90 + rnd)
-            proof_dev = pf.prove_fast_tpu(params, pk, cs,
-                                          randint=lambda: r1.randrange(R))
-            proof_host = pf.prove_fast(params, pk, cs,
-                                       randint=lambda: r2.randrange(R))
-            assert proof_dev == proof_host, f"round {rnd} diverged"
-            assert verify(params, pk, cs.public_values(), proof_dev)
-            dp_now = pf._DEVICE_PROVERS[0][1]
-            key = id(pk)
-            if key in seen:
-                assert seen[key] is dp_now, "DeviceProver was rebuilt"
-            seen[key] = dp_now
-        assert len(pf._DEVICE_PROVERS) == 2
-        # the inactive prover must be suspended (no resident ext tables)
-        inactive = pf._DEVICE_PROVERS[1][1]
-        assert inactive.fixed_ext == [] and inactive.sigma_ext == []
-
-        # deep suspend drops the static tables too; resume + prove must
-        # still match the host byte-for-byte
-        params, pk, cs = pa
-        dp_a = next(d for p0, d in pf._DEVICE_PROVERS if p0 is pk)
-        dp_a.suspend(deep=True)
-        assert not dp_a._tables_live
-        r1, r2 = random.Random(1234), random.Random(1234)
-        proof_dev = pf.prove_fast_tpu(params, pk, cs,
-                                      randint=lambda: r1.randrange(R))
-        proof_host = pf.prove_fast(params, pk, cs,
-                                   randint=lambda: r2.randrange(R))
-        assert proof_dev == proof_host
-        assert dp_a._tables_live
-    finally:
-        pf._DEVICE_PROVERS.clear()

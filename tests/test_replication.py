@@ -117,9 +117,13 @@ def _attest_pairs(client, kps, pairs):
         client.attest(about, value)
 
 
-def _settled(url, min_edges=0):
+def _settled(url, min_edges=0, min_attestations=0):
+    """The leader has ingested ``min_attestations`` (latest-wins churn
+    leaves the edge count flat, so edges alone cannot say a batch
+    landed) and published its latest revision."""
     st = _get_json(url + "/status")
     return (st["graph"]["edges"] >= min_edges
+            and st["tailer"]["attestations"] >= min_attestations
             and st["last_refresh"]["revision"]
             == st["graph"]["revision"])
 
@@ -222,9 +226,9 @@ def test_scores_etag_304_and_invalidation(tmp_path, devnet):
         # churn invalidates: a new revision must serve 200 + new ETag
         rev0 = _get_json(url + "/status")["graph"]["revision"]
         _attest_pairs(client, kps, [(0, addrs[1], 11)])
-        _wait(lambda: _settled(url)
-              and _get_json(url + "/status")["graph"]["revision"]
-              > rev0, what="revision bump")
+        _wait(lambda: _settled(url, min_attestations=3),
+              what="revision bump published")
+        assert _get_json(url + "/status")["graph"]["revision"] > rev0
         status, h3, _ = _get(url + "/scores",
                              headers={"If-None-Match": etag})
         assert status == 200 and h3["ETag"] != etag
@@ -257,7 +261,8 @@ def test_follower_bootstrap_tail_byte_equality(tmp_path, devnet):
         _attest_pairs(client, kps,
                       [(0, addrs[2], 5), (2, addrs[0], 3),
                        (1, addrs[2], 4)])
-        _wait(lambda: _settled(url, min_edges=4), what="leader settle 2")
+        _wait(lambda: _settled(url, min_edges=4, min_attestations=5),
+              what="leader settle 2")
         _wait(lambda: _follower_caught_up(furl, url),
               what="follower catch-up")
         ls, lh, lbody = _get(url + "/scores")
@@ -351,7 +356,8 @@ def test_follower_kill_restart_resumes_from_cursor(tmp_path, devnet):
         _hard_kill_follower(fol)
         # churn while the follower is down
         _attest_pairs(client, kps, [(2, addrs[0], 6), (1, addrs[2], 8)])
-        _wait(lambda: _settled(url), what="leader settle 2")
+        _wait(lambda: _settled(url, min_attestations=5),
+              what="leader settle 2")
         fol2 = _follower(tmp_path, url)
         # the constructor restored local state BEFORE any network I/O:
         # same records, same cursor — its own cursor, not 0:0
@@ -398,7 +404,9 @@ def test_leader_compaction_ship_floor(tmp_path, devnet):
         rounds = [(i % 3, addrs[(i + 1) % 3], 2 + i % 9)
                   for i in range(12)]
         _attest_pairs(client, kps, rounds)
-        _wait(lambda: _settled(url, min_edges=3), what="leader settle")
+        _wait(lambda: _settled(url, min_edges=3,
+                               min_attestations=len(rounds)),
+              what="leader settle")
         _wait(lambda: len(svc.store.wal.segments()) >= 2,
               what="segment rotation")
         segs0 = len(svc.store.wal.segments())
@@ -484,7 +492,8 @@ def test_follower_local_wal_bounded_under_churn(tmp_path, devnet):
                            (1, addrs[2], 40 + r),
                            (2, addrs[0], 70 + r)])
             shipped += 3
-            _wait(lambda: _settled(url, min_edges=3),
+            _wait(lambda: _settled(url, min_edges=3,
+                                   min_attestations=shipped),
                   what=f"leader settle round {r}")
             _wait(lambda: _follower_caught_up(furl, url),
                   what=f"follower catch-up round {r}")

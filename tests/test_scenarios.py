@@ -190,7 +190,10 @@ def _hist_count(name):
 def test_topic_batch_matches_independent_converges(tracer):
     """K vmapped topic vectors through ONE routed operator == K
     independent converges (to 1e-12 relative), with exactly ONE
-    routing-plan build paid for all K topics."""
+    routing-plan build paid for all K topics. Both sides run in float64:
+    the backends default to float32, whose last-ulp differences between
+    the vmapped and the single-vector programs the 1e-12 bound would
+    read as divergence."""
     n, k = 400, 5
     src, dst, val = barabasi_albert_edges(n, 4, seed=11)
     rng = np.random.default_rng(11)
@@ -198,13 +201,13 @@ def test_topic_batch_matches_independent_converges(tracer):
 
     seq = []
     for topic in range(k):
-        s, iters, _ = JaxRoutedBackend().converge_edges(
+        s, iters, _ = JaxRoutedBackend(dtype=np.float64).converge_edges(
             n, src, dst, val, None, 1000.0, 100, tol=1e-6, alpha=0.1,
             s0=s0k[topic])
         seq.append((s, int(iters)))
     builds_before = _hist_count("routed_plan_build_seconds")
 
-    scores, iters, delta = JaxRoutedBackend().converge_topics(
+    scores, iters, delta = JaxRoutedBackend(dtype=np.float64).converge_topics(
         n, src, dst, val, None, s0k, 100, tol=1e-6, alpha=0.1)
     builds_after = _hist_count("routed_plan_build_seconds")
     assert builds_after - builds_before == 1, \

@@ -3,11 +3,12 @@ host scalar implementation — the ingest validation layer at scale
 (SURVEY.md §7.2 step 5; reference hot spots ``ecdsa/native.rs:298-331``
 recover and ``:382-395`` verify).
 
-One module-scoped fixture drives everything so the 256-step Strauss
-ladder compiles once per batch shape."""
+One module-scoped fixture drives everything, and every ladder call runs
+at its lane count (``BATCH``), so each ladder compiles once."""
 
 import random
 
+import numpy as np
 import pytest
 
 from protocol_tpu.crypto.secp256k1 import (
@@ -19,6 +20,16 @@ from protocol_tpu.ops import secp_batch as sb
 
 rng = random.Random(0x5EC9)
 BATCH = 6
+
+
+def _chunks(rows, pad):
+    """``rows`` in BATCH-lane chunks, each returned as columns with its
+    count of real rows; the last is filled up with ``pad`` rows, the way
+    client/ingest.py pads a batch."""
+    for lo in range(0, len(rows), BATCH):
+        chunk = rows[lo:lo + BATCH]
+        cols = zip(*(chunk + [pad] * (BATCH - len(chunk))))
+        yield [list(c) for c in cols], len(chunk)
 
 
 @pytest.fixture(scope="module")
@@ -148,17 +159,25 @@ class TestRecoverImpliesVerify:
                          rng2.randrange(1, sb.SECP_N)))
         return rows
 
-    def test_mask_equals_scalar_recover_then_verify(self, population):
+    @pytest.fixture(scope="class")
+    def recovered(self, population):
+        """The population through ``recover_batch``, BATCH lanes at a
+        time: (xs, ys, valid) for its rows."""
+        xs, ys, valid = [], [], []
+        for cols, k in _chunks(population, (1, 1, 0, 1)):
+            cx, cy, cvalid = sb.recover_batch(*cols)
+            xs += cx[:k]
+            ys += cy[:k]
+            valid.append(cvalid[:k])
+        return xs, ys, np.concatenate(valid)
+
+    def test_mask_equals_scalar_recover_then_verify(self, population,
+                                                    recovered):
         """new-path valid == the scalar pipeline (recover, then verify
         with the recovered key), lane for lane."""
-        from protocol_tpu.crypto.secp256k1 import (
-            PublicKey, Signature)
+        from protocol_tpu.crypto.secp256k1 import Signature
 
-        rs = [r for r, _, _, _ in population]
-        ss = [s for _, s, _, _ in population]
-        recs = [c for _, _, c, _ in population]
-        ms = [m for _, _, _, m in population]
-        xs, ys, valid = sb.recover_batch(rs, ss, recs, ms)
+        xs, ys, valid = recovered
         for i, (r, s, c, m) in enumerate(population):
             try:
                 pk = recover_public_key(Signature(r, s, c), m)
@@ -171,15 +190,16 @@ class TestRecoverImpliesVerify:
             if valid[i]:
                 assert (xs[i], ys[i]) == (pk.point.x, pk.point.y)
 
-    def test_valid_lanes_pass_the_redundant_ladder(self, population):
+    def test_valid_lanes_pass_the_redundant_ladder(self, population,
+                                                   recovered):
         """Every valid lane survives the full verification ladder —
         the audit-mode cross-check can never change the mask."""
-        rs = [r for r, _, _, _ in population]
-        ss = [s for _, s, _, _ in population]
-        recs = [c for _, _, c, _ in population]
-        ms = [m for _, _, _, m in population]
-        xs, ys, valid = sb.recover_batch(rs, ss, recs, ms)
-        ok = sb.verify_batch(rs, ss, ms, list(zip(xs, ys)))
+        xs, ys, valid = recovered
+        rows = [(r, s, m, q) for (r, s, _, m), q in
+                zip(population, zip(xs, ys))]
+        ok = np.concatenate([
+            sb.verify_batch(*cols)[:k]
+            for cols, k in _chunks(rows, (1, 1, 1, (0, 0)))])
         assert ((valid & ok) == valid).all()
 
 
@@ -224,13 +244,12 @@ class TestRecoverStream:
 
     def test_stream_matches_batch_per_chunk(self, signed):
         _, msgs, sigs, _ = signed
+        cols = ([s.r for s in sigs], [s.s for s in sigs],
+                [s.rec_id for s in sigs], msgs)
+        # two chunks of BATCH lanes, the second the first rotated: a
+        # result handed to the wrong chunk cannot match
         half = BATCH // 2
-        chunks = []
-        for lo, hi in ((0, half), (half, BATCH)):
-            chunks.append(([s.r for s in sigs[lo:hi]],
-                           [s.s for s in sigs[lo:hi]],
-                           [s.rec_id for s in sigs[lo:hi]],
-                           msgs[lo:hi]))
+        chunks = [cols, tuple(c[half:] + c[:half] for c in cols)]
         streamed = list(sb.recover_stream(iter(chunks)))
         assert len(streamed) == len(chunks)
         for ch, (xs, ys, valid) in zip(chunks, streamed):
