@@ -264,8 +264,9 @@ class JaxRoutedBackend(JaxSparseBackend):
         with trace.span("converge.stage", backend="jax-routed"):
             arrs, static = routed_arrays(op, dtype=self.dtype, alpha=alpha)
             if s0 is None:
-                s0 = jnp.asarray(op.initial_scores(initial_score,
-                                                   dtype=self.dtype))
+                # the cold start from the resident 0/1 mask: exactly
+                # op.initial_scores, with nothing uploaded
+                s0 = arrs["valid"] * float(initial_score)
             else:
                 # node-order warm start → state-slot order
                 s0 = jnp.asarray(op.scores_from_nodes(np.asarray(s0),

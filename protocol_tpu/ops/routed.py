@@ -462,6 +462,12 @@ class RoutedOperator:
     # load as 8 (the only default any cached operator was built with).
     min_width: int = 8
 
+    def __post_init__(self):
+        # routed_arrays' resident staging, keyed (dtype, alpha, pallas):
+        # not a field, so save() never writes it and the device arrays
+        # are freed with the operator
+        self._staged: dict = {}
+
     @property
     def n_state(self) -> int:
         return 1 << self.state_e
@@ -727,9 +733,32 @@ def routed_arrays(op: RoutedOperator, dtype=jnp.float32, alpha: float = 0.0,
     path's node-order vector, must be in **state order** with length
     ``2^state_e`` (zero on dead slots) — translate a node-order vector u
     via ``u[op.state_to_node] * (op.state_to_node >= 0)`` padded to
-    ``op.n_state``; the default is uniform over valid peers."""
-    if pallas is None:
-        pallas = _use_pallas()
+    ``op.n_state``; the default is uniform over valid peers.
+
+    With the default pre-trust the arrays stay resident on ``op``: it is
+    staged once per ``(dtype, alpha, pallas)``, and every later call
+    uploads nothing. An explicit ``pretrust`` is staged afresh each call
+    and not kept. Each call returns a new dict over the shared arrays,
+    so a caller may replace its entries (the delta engine patches its
+    own) without reaching ``op`` or another caller; nothing may donate
+    them. The ``routed_stage`` counter counts each call as a ``hit`` or
+    a ``miss``."""
+    pallas = _use_pallas() if pallas is None else bool(pallas)
+    key = (np.dtype(dtype), float(alpha), pallas)
+    staged = op._staged.get(key) if pretrust is None else None
+    trace.counter("routed_stage").inc(
+        result="miss" if staged is None else "hit")
+    if staged is None:
+        staged = _stage_routed(op, dtype, alpha, pretrust, pallas)
+        if pretrust is None:
+            op._staged[key] = staged
+    arrs, static = staged
+    return dict(arrs), static
+
+
+def _stage_routed(op: RoutedOperator, dtype, alpha: float, pretrust,
+                  pallas: bool):
+    """Upload ``op``'s arrays for :func:`routed_arrays`."""
     if pretrust is None:
         pretrust = op.valid.astype(np.float64) / max(op.n_valid, 1)
     arrs = {
@@ -763,7 +792,7 @@ def routed_arrays(op: RoutedOperator, dtype=jnp.float32, alpha: float = 0.0,
         edge_bits=op.edge_bits,
         state_e=op.state_e,
         state_bits=op.state_bits,
-        pallas=bool(pallas),
+        pallas=pallas,
     )
     return arrs, static
 

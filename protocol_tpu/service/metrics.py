@@ -134,6 +134,8 @@ HISTOGRAM_FAMILIES = {
 # recompile count of 0 and a shed count of 0 under the watermark — the
 # series must exist to be assertable)
 DECLARED_COUNTERS = ("xla_compiles", "xla_cache_hits",
+                     # routed_arrays: operator staged (miss) or resident (hit)
+                     "routed_stage",
                      "xla_steady_recompiles",
                      "operator_full_builds", "refresh_sweep_scope",
                      "proof_pool_shed", "proof_pool_affinity",

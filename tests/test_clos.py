@@ -199,6 +199,86 @@ def test_inner_fused_counter_per_staged_operator(tracer):
         assert tracer.counter_total("clos_inner_fused", route=route) == want
 
 
+def _staged_converge(arrs, static, op):
+    s0 = jnp.asarray(op.initial_scores(1000.0))
+    s, it, dl = converge_routed_adaptive(arrs, static, s0, tol=1e-6,
+                                         max_iterations=300)
+    return op.scores_for_nodes(np.asarray(s)), int(it), float(dl)
+
+
+def test_staging_stays_resident_per_operator(tracer):
+    """A second converge on one operator stages nothing and returns the
+    first's scores, iterations and delta bit for bit, as does a freshly
+    built operator and the host-built start vector."""
+    from protocol_tpu.backend import JaxRoutedBackend
+
+    n, m = 1200, 4
+    src, dst, val = barabasi_albert_edges(n, m, seed=5)
+    valid = np.ones(n, dtype=bool)
+    be = JaxRoutedBackend()
+
+    def converge(op):
+        return be.converge_edges(n, src, dst, val, valid, 1000.0, 300,
+                                 tol=1e-6, alpha=0.1, operator=op)
+
+    op = build_routed_operator(n, src, dst, val)
+    first, second = converge(op), converge(op)
+    assert tracer.counter_total("routed_stage", result="miss") == 1
+    assert tracer.counter_total("routed_stage", result="hit") == 1
+    fresh = build_routed_operator(n, src, dst, val)
+    host_start = _staged_converge(*routed_arrays(fresh, alpha=0.1), fresh)
+    for got in (second, converge(fresh), host_start):
+        assert np.array_equal(got[0], first[0])
+        assert got[1:] == first[1:]
+
+
+def test_staging_is_shared_only_within_its_key(tracer):
+    """Another alpha or an explicit pre-trust never gets the resident
+    arrays, each converges as its own fresh staging does, and a
+    caller's patch to its dict reaches no later caller."""
+    n, m = 1200, 4
+    src, dst, val = barabasi_albert_edges(n, m, seed=5)
+    op = build_routed_operator(n, src, dst, val)
+    pt = op.valid * (np.arange(op.n_state) % 3 + 1.0)
+    pt /= pt.sum()
+    resident, static = routed_arrays(op, alpha=0.1)
+    resident["valid"] = None
+    damped, _ = routed_arrays(op, alpha=0.2)
+    pretrusted, _ = routed_arrays(op, alpha=0.1, pretrust=pt)
+    again, _ = routed_arrays(op, alpha=0.1)
+    assert tracer.counter_total("routed_stage", result="miss") == 3
+    assert tracer.counter_total("routed_stage", result="hit") == 1
+    assert again is not resident and again["valid"] is not None
+    assert again["out_weight"][0] is resident["out_weight"][0]
+    for other in (damped, pretrusted):
+        assert other["out_weight"][0] is not again["out_weight"][0]
+    assert again["pretrust"] is resident["pretrust"]
+    base = _staged_converge(again, static, op)
+    for arrs, kw in ((damped, dict(alpha=0.2)),
+                     (pretrusted, dict(alpha=0.1, pretrust=pt))):
+        fresh = build_routed_operator(n, src, dst, val)
+        got = _staged_converge(arrs, static, op)
+        want = _staged_converge(*routed_arrays(fresh, **kw), fresh)
+        assert np.array_equal(got[0], want[0]) and got[1:] == want[1:]
+        assert not np.array_equal(got[0], base[0])
+
+
+def test_loaded_operator_stages_afresh(tracer, tmp_path):
+    """The resident arrays are not saved: a loaded operator stages its
+    own, equal to the saved operator's."""
+    n, m = 600, 3
+    src, dst, val = barabasi_albert_edges(n, m, seed=13)
+    rop = build_routed_operator(n, src, dst, val)
+    arrs, _ = routed_arrays(rop, alpha=0.1)
+    rop.save(tmp_path / "op")
+    again, _ = routed_arrays(RoutedOperator.load(tmp_path / "op"), alpha=0.1)
+    assert tracer.counter_total("routed_stage", result="miss") == 2
+    assert tracer.counter_total("routed_stage", result="hit") == 0
+    assert again["out_weight"][0] is not arrs["out_weight"][0]
+    for a, b in zip(again["out_weight"], arrs["out_weight"]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_identity_route_is_identity():
     E = 1 << 10
     plan = plan_route_py(np.arange(E))

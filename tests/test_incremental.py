@@ -236,6 +236,37 @@ def test_delta_engine_matches_rebuild_under_mixed_churn():
             f"round {round_}: iterations {it_e} vs {it_r}"
 
 
+def test_engine_patches_stay_off_the_operators_staging():
+    """The engine anchors on the operator's resident staging and patches
+    only its own dict: after a revision batch a converge on the operator
+    still returns the pre-patch scores."""
+    from protocol_tpu.utils import trace
+
+    trace.enable()
+    misses = trace.counter_total("routed_stage", result="miss")
+    hits = trace.counter_total("routed_stage", result="hit")
+    n = 160
+    src, dst, val = barabasi_albert_edges(n, 3, seed=1)
+    valid = np.ones(n, dtype=bool)
+    op = build_routed_operator(n, src, dst, val, valid)
+
+    def converge():
+        return JaxRoutedBackend().converge_edges(
+            n, src, dst, val, valid, INITIAL, MAX_IT, tol=TOL, operator=op)
+
+    before = converge()
+    eng = DeltaEngine.anchor(n, src, dst, val, valid, op)
+    edges = _edge_dict(n, src, dst, val)
+    assert eng.apply_deltas([(i, j, v, 3.0 * v + 1.0) for (i, j), v
+                             in list(edges.items())[:10]], n=n), eng.stats
+    patched = eng.converge(eng.initial_node_scores(INITIAL), MAX_IT, TOL)
+    after = converge()
+    assert np.array_equal(after[0], before[0]) and after[1:] == before[1:]
+    assert not np.allclose(patched[0], before[0])
+    assert trace.counter_total("routed_stage", result="miss") == misses + 1
+    assert trace.counter_total("routed_stage", result="hit") == hits + 2
+
+
 # --- partial refresh ---------------------------------------------------------
 
 

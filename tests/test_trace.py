@@ -552,6 +552,29 @@ def test_converge_spans_stage_edges_fetch(tracer, backend, entry):
         assert not set(plan) & set(spans)
 
 
+def test_routed_converge_twice_stages_once(tracer):
+    """Two converges on one routed operator: each runs the three spans,
+    and only the first stages the operator."""
+    np = pytest.importorskip("numpy")
+    from protocol_tpu.backend import JaxRoutedBackend
+    from protocol_tpu.graph import barabasi_albert_edges
+    from protocol_tpu.ops.routed import build_routed_operator
+
+    n = 200
+    src, dst, val = barabasi_albert_edges(n, 3, seed=5)
+    valid = np.ones(n, dtype=bool)
+    op = build_routed_operator(n, src, dst, val, valid)
+    be = JaxRoutedBackend()
+    for _ in range(2):
+        be.converge_edges(n, src, dst, val, valid, 1000.0, 100, tol=1e-6,
+                          alpha=0.1, operator=op)
+    spans = trace.summary()
+    for name in ("converge.stage", "converge.edges", "converge.fetch"):
+        assert spans[name]["count"] == 2, name
+    assert trace.counter_total("routed_stage", result="miss") == 1
+    assert trace.counter_total("routed_stage", result="hit") == 1
+
+
 def test_record_converge_stats_instruments(tracer):
     from protocol_tpu.ops.converge import record_converge_stats
 
